@@ -218,6 +218,7 @@ def collectives_plan(mode: str) -> list:
     import jax
     import jax.numpy as jnp
     from repro.dist.overlap import make_ag_matmul, make_rs_matmul, plan_ring
+    from repro.launch.mesh import make_mesh
 
     n_dev = jax.device_count()
     p = n_dev if n_dev >= 2 else 4
@@ -229,7 +230,7 @@ def collectives_plan(mode: str) -> list:
         out.append(f"ring_hlo_{mode},0,skipped=1 device "
                    "(set XLA_FLAGS=--xla_force_host_platform_device_count=4)")
         return out
-    mesh = jax.make_mesh((p,), ("model",))
+    mesh = make_mesh((p,), ("model",))
     x = jax.ShapeDtypeStruct((4 * p, 2 * p), jnp.float32)
     w = jax.ShapeDtypeStruct((2 * p, 2 * p), jnp.float32)
     for kind, make in (("ag", make_ag_matmul), ("rs", make_rs_matmul)):
@@ -260,6 +261,7 @@ def collectives_bench(quick: bool) -> list:
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.dist.overlap import make_ag_matmul, make_rs_matmul
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import overlap_wire_bytes
 
     n_dev = jax.device_count()
@@ -267,7 +269,7 @@ def collectives_bench(quick: bool) -> list:
         return ["collectives_ab_skip,0,needs >=2 devices "
                 "(set XLA_FLAGS=--xla_force_host_platform_device_count=4)"]
     p = n_dev
-    mesh = jax.make_mesh((p,), ("model",))
+    mesh = make_mesh((p,), ("model",))
     m = 256 if quick else 1024
     k = n = 16 * p
     key = jax.random.PRNGKey(0)
@@ -319,16 +321,15 @@ def serve_dry() -> list:
     Walks ``repro.serve.plan_decode`` for a forced single-host 4-way
     tensor-parallel mesh (DCN-free by construction: one host, so the
     hierarchy tops out at the ICI) and asserts the page level picked a
-    page that fits the VMEM leaf double-buffered -- the CI serve smoke
+    page whose kernel working set fits the VMEM leaf -- the CI serve smoke
     gate (``ci/run_tests.sh`` greps ``dcn_free=True`` and
     ``page_fits_vmem=True``).
     """
     from jax.sharding import AbstractMesh
     from repro.configs import get_model_config
-    from repro.core.plan import PAGE_BUFFERING
     from repro.serve import page_spec_from_plan, plan_decode
 
-    mesh = AbstractMesh((("data", 1), ("model", 4)))
+    mesh = AbstractMesh((1, 4), ("data", "model"))
     cfg = get_model_config("llama3.2-1b")
     hp = plan_decode(cfg, mesh, max_len=32768, batch=8)
     out = []
@@ -338,7 +339,7 @@ def serve_dry() -> list:
     page = hp.page_plan()
     vmem = hp.level("VMEM")
     fits = (page is not None and vmem is not None
-            and PAGE_BUFFERING * page["page_bytes"] <= vmem.budget_bytes)
+            and page["vmem_bytes"] <= vmem.budget_bytes)
     spec = page_spec_from_plan(hp, cfg)
     out.append(
         f"serve_dry_summary,0,levels={'>'.join(levels)};"
@@ -1029,7 +1030,7 @@ def dry(_quick: bool, collectives: str = "gspmd") -> list:
     from jax.sharding import AbstractMesh
 
     out = plans(True)
-    mesh = AbstractMesh((("data", 16), ("model", 16)))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     for arch in ("llama3.2-1b", "deepseek-v2-236b"):
         cfg = get_model_config(arch)
         rules = arch_rules(cfg, mesh)
@@ -1077,21 +1078,16 @@ def _write_json(path: str, rows: list, argv: list) -> None:
     number a year later.  Schema checked by the CI smoke."""
     import json
 
-    backend = device = "unknown"
-    if "jax" in sys.modules:
-        try:
-            import jax
+    import jax
 
-            backend = jax.default_backend()
-            device = jax.devices()[0].device_kind
-        except Exception:
-            pass
+    dev = jax.devices()[0]
     doc = {
         "schema": "repro-bench-v1",
         "created_unix": int(time.time()),
         "argv": argv,
-        "backend": backend,
-        "device": device,
+        "backend": dev.platform,
+        "device": dev.device_kind,
+        "device_count": len(jax.devices()),
         "rows": [
             {"section": sec, "name": name, "us_per_call": us,
              "derived": _parse_derived(derived)}
@@ -1146,6 +1142,9 @@ def main() -> None:
         # which only the section bodies perform).
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     rows: list = []
     if args.dry:
         # CI gate: unlike the benchmark sections below, failures here must
@@ -1172,17 +1171,20 @@ def main() -> None:
         return
     names = args.only.split(",") if args.only else list(SECTIONS)
     print("name,us_per_call,derived")
+    failed = []
     for name in names:
         fn = SECTIONS[name.strip()]
-        t0 = time.perf_counter()
         try:
             for line in fn(args.quick):
                 _collect(rows, name.strip(), line)
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # record it, run the other sections, fail
             _collect(rows, name.strip(), f"{name}_ERROR,0,{e!r}")
+            failed.append(name.strip())
         sys.stdout.flush()
     if args.json:
         _write_json(args.json, rows, sys.argv[1:])
+    if failed:
+        sys.exit(f"sections failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -41,8 +41,8 @@ printf '%s\n' "$plan_out" | grep -q 'np_raw=5 quantized=8' \
 
 echo "== smoke: plan-driven serving (forced 4-device dry) =="
 # The serving engine's decode plan end to end on every run: a single-host
-# 4-way TP mesh must produce a DCN-free plan whose KV page fits the VMEM
-# leaf double-buffered (DESIGN.md §7).
+# 4-way TP mesh must produce a DCN-free plan whose KV page, as the paged
+# kernel lays it out in VMEM, fits the VMEM leaf (DESIGN.md §7).
 serve_out="$(XLA_FLAGS="--xla_force_host_platform_device_count=4" \
     python -m benchmarks.run --only serve --dry)"
 printf '%s\n' "$serve_out"
@@ -138,9 +138,9 @@ print(f"BENCH json OK: {len(doc['rows'])} rows")
 EOF
 rm -f "$bench_json"
 
-echo "== smoke: committed BENCH_10.json (obs trajectory) =="
+echo "== smoke: committed BENCH_10.json (obs artifact) =="
 # The committed observability benchmark artifact must stay parseable
-# against the same schema so the perf trajectory remains readable.
+# against the same schema.
 python - BENCH_10.json <<'EOF'
 import json, sys
 doc = json.load(open(sys.argv[1]))

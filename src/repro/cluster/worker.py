@@ -16,14 +16,14 @@ Two transports share the loop verbatim:
 
   * ``"proc"`` -- a ``multiprocessing`` *spawn* context worker with a
     ``ctx.Queue`` pair.  The factory must be picklable (``EngineSpec`` /
-    ``StubSpec``); this is the production shape, one JAX runtime per
-    replica.
+    ``StubSpec``); one JAX runtime per replica, on CPU hosts only: a TPU
+    chip belongs to one process, and the front side already holds it.
   * ``"thread"`` -- a daemon thread with ``queue.Queue``s in-process.
     Same protocol, no pickling, and the live engine is reachable for
     LIVE telemetry (``Replica.stats`` reads ``engine.stats()`` directly,
     so a replica's free-page count moves WHILE a request is resident --
-    what the ``free_pages`` routing policy keys on).  Tests and the
-    single-host benchmark use this.
+    what the ``free_pages`` routing policy keys on).  Tests, the
+    single-host benchmark and TPU hosts (one replica per chip) use this.
 """
 
 from __future__ import annotations
@@ -111,7 +111,9 @@ _ENGINE_CACHE: Dict[Any, Any] = {}
 class EngineSpec:
     """A picklable ``ServeEngine`` recipe: everything the worker needs to
     rebuild the engine on its side of the spawn.  ``chip`` is a tuple of
-    ``chip_spec`` override items (tests shrink VMEM with it)."""
+    ``chip_spec`` override items (tests shrink VMEM with it).  Replica ``r``
+    runs on a one-device mesh over device ``r`` (modulo the device count):
+    co-resident replicas on a multi-chip host each hold one chip."""
 
     arch: str = "llama3.2-1b"
     reduced: bool = True
@@ -129,16 +131,19 @@ class EngineSpec:
         key = (self, replica)
         eng = _ENGINE_CACHE.get(key)
         if eng is None:
+            import jax
+
             from repro.configs.base import get_model_config
             from repro.hw.tpu import chip_spec
-            from repro.launch.mesh import make_host_mesh
+            from repro.launch.mesh import make_device_mesh
             from repro.serve.engine import ServeEngine, ServePolicy
 
             cfg = get_model_config(self.arch)
             if self.reduced:
                 cfg = cfg.reduced()
+            devices = jax.devices()
             eng = ServeEngine(
-                cfg, make_host_mesh(),
+                cfg, make_device_mesh(devices[replica % len(devices)]),
                 policy=ServePolicy(
                     max_new_tokens=self.max_new_tokens,
                     max_slots=self.max_slots, max_len=self.max_len,
@@ -367,6 +372,15 @@ class Replica:
         self._calls: Dict[int, _Call] = {}
         self._lock = threading.Lock()
         self._closed = False
+        if transport == "proc":
+            import jax
+
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    "transport='proc' cannot serve on a TPU: this process "
+                    "already holds the chip, so a spawned replica would "
+                    "fail or hang reaching it; use transport='thread' (one "
+                    "replica per device)")
         if transport == "thread":
             self._inq: Any = queue.Queue()
             self._outq: Any = queue.Queue()

@@ -73,6 +73,7 @@ __all__ = [
     "PlanPolicy",
     "Workload",
     "leaf_matmul_plan",
+    "page_vmem_bytes",
     "plan_run",
     "quantize_divisor",
 ]
@@ -112,6 +113,42 @@ PAGE_ALIGN = 8
 PAGE_BUFFERING = 2
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def page_vmem_bytes(page_tokens: int, n_kv: int, head_dim: int,
+                    q_heads: int, dtype_bytes: int, lane: int = 128) -> int:
+    """VMEM that ``kernels.paged_attention`` holds to stream one page of
+    ``page_tokens`` tokens -- the page level's phi.
+
+    Counted in Mosaic's tiled layout, not in raw KV bytes: a K or V block
+    is ``(page_tokens, n_kv, head_dim)`` with ``head_dim`` on the lanes
+    (the pool pads it to whole lane rows) and the KV heads on the
+    sublanes (padded first to 8 by the kernel itself, then to the dtype's
+    sublane granule), and both are double-buffered.  The head-batched
+    QK^T and PV contractions add one relaid copy of the K and V blocks and
+    two f32 ``(q_heads, page_tokens, lanes)`` products (both terms fitted
+    to what the v5e compiler allocates), plus the per-slot query/output
+    blocks, running max/sum/accumulator scratch and ``(kv, group, page)``
+    f32 score temporaries.  Checked against the compiler's own VMEM
+    accounting in ``tests/test_chip_compile.py``."""
+    sub = max(8, (4 // max(1, dtype_bytes)) * 8)
+    kv = _round_up(max(1, n_kv), 8)
+    g = max(1, -(-max(1, q_heads) // max(1, n_kv)))
+    h = kv * g
+    lanes = _round_up(head_dim, lane)
+    kv_token = _round_up(kv, sub) * lanes * dtype_bytes
+    pages = PAGE_BUFFERING * 2 * page_tokens * kv_token
+    relaid = 2 * page_tokens * kv_token
+    products = 2 * max(1, q_heads) * page_tokens * lanes * 4
+    q_out = PAGE_BUFFERING * (kv * _round_up(g, sub) + _round_up(h, sub)) \
+        * lanes * dtype_bytes
+    scratch = 3 * _round_up(h, 8) * lanes * 4
+    scores = 4 * kv * _round_up(g, 8) * _round_up(page_tokens, lane) * 4
+    return pages + relaid + products + q_out + scratch + scores
+
+
 # ---------------------------------------------------------------------------
 # Inputs: what to plan (Workload) and how (PlanPolicy)
 # ---------------------------------------------------------------------------
@@ -132,8 +169,9 @@ class Workload:
     The decode (serving) workload adds the KV-cache terms (``repro.serve``):
     ``kv_bytes_per_token`` is the *global* per-token KV footprint (bytes x
     heads x layers), ``kv_layers``/``kv_heads`` its layer count and
-    shardable head extent, ``max_tokens`` the per-sequence resident-token
-    bound.  Mesh levels then choose the KV head sharding (recorded as
+    shardable head extent (0: one latent read as both K and V, MLA),
+    ``q_heads`` the query heads attending to it (0: as many as KV heads),
+    ``max_tokens`` the per-sequence resident-token bound.  Mesh levels then choose the KV head sharding (recorded as
     ``detail["kv_shard"]``), and the ``PAGE_LEVEL_NAMES`` leaf runs the
     page search: partition one sequence's resident KV token range until
     one partition -- a *page* -- fits the leaf budget double-buffered.
@@ -148,6 +186,7 @@ class Workload:
     kv_bytes_per_token: int = 0
     kv_layers: int = 1
     kv_heads: int = 0
+    q_heads: int = 0
     max_tokens: int = 0
 
 
@@ -382,6 +421,7 @@ class HierarchicalPlan:
                 lines.append(
                     f"{ind}{lp.level}[page] page_tokens={pg['page_tokens']} "
                     f"page={_fmt(pg['page_bytes'])} x{pg['buffering']} "
+                    f"vmem={_fmt(pg['vmem_bytes'])} "
                     f"kv_shard={pg['kv_shard']} np={lp.np} "
                     f"budget={_fmt(lp.budget_bytes)} fits={lp.fits} "
                     f"phi={lp.phi} src={pg.get('source', 'analytic')}")
@@ -643,23 +683,27 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
     KV slice of one sequence after head sharding, so the domain element is
     ``kv_bytes_per_token / (kv_layers * kv_shard)`` bytes and the search
     partitions ``max_tokens`` of them until one partition -- a *page*,
-    sublane-aligned and double-buffered -- fits the leaf budget.  The
-    smallest np that fits gives the largest page, i.e. the fewest
-    page-boundary crossings per token, exactly the paper's "largest
-    partition that still fits the TCL" optimality argument.
+    sublane-aligned -- fits the leaf budget as the kernel lays it out in
+    VMEM (``page_vmem_bytes``: tiled, double-buffered, with the kernel's
+    temporaries).  The smallest np that fits gives the largest page, i.e.
+    the fewest page-boundary crossings per token, exactly the paper's
+    "largest partition that still fits the TCL" optimality argument.  The
+    budget is also the ``vmem_limit_bytes`` the kernel compiles under
+    (``detail["page"]["vmem_limit_bytes"]``).
     """
     budget = int(level.per_core_size() * policy.vmem_fraction)
     granule = level.cache_line_size or DEFAULT_GRANULE
     layers = max(1, workload.kv_layers)
-    tok_bytes = max(1, -(-workload.kv_bytes_per_token
-                         // (layers * max(1, kv_shard))))
+    shard = max(1, kv_shard)
+    tok_bytes = max(1, -(-workload.kv_bytes_per_token // (layers * shard)))
     tokens = max(PAGE_ALIGN, workload.max_tokens)
     dist = Array1DDistribution(length=tokens, element_size=tok_bytes)
+    geometry = _page_geometry(workload, tok_bytes, shard)
 
     def phi_page(_line: int, d: Distribution, np_: int) -> float:
         toks = -(-math.ceil(d.get_average_partition_size(np_))
                  // PAGE_ALIGN) * PAGE_ALIGN
-        return float(PAGE_BUFFERING * toks * d.get_element_size())
+        return float(page_vmem_bytes(toks, *geometry))
 
     try:
         # The mesh partitioning was already consumed by the per-shard
@@ -680,10 +724,11 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
     tuning = None
     if policy.use_tuned and fits:
         tuned_pt, tuning = _tuned_page_tokens(policy, tok_bytes, tokens,
-                                              budget)
+                                              budget, geometry)
         if tuned_pt is not None:
             page_tokens, source = tuned_pt, "tuned"
     page_bytes = page_tokens * tok_bytes
+    vmem_bytes = page_vmem_bytes(page_tokens, *geometry)
     n_pages = -(-tokens // page_tokens)
     # Pool geometry (the paged engine's bounds, DESIGN.md §8): one logical
     # page costs ``page_tokens x kv_bytes_per_token`` GLOBAL bytes; the
@@ -700,10 +745,12 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
         budget_bytes=budget, granule_bytes=granule,
         n_workers=max(1, n_workers), extent=n_pages,
         np_raw=np_raw, np=n_pages,
-        partition_bytes=float(PAGE_BUFFERING * page_bytes), fits=fits,
+        partition_bytes=float(vmem_bytes), fits=fits,
         detail={"page": {
             "page_tokens": page_tokens,
             "page_bytes": page_bytes,
+            "vmem_bytes": vmem_bytes,
+            "vmem_limit_bytes": budget,
             "tok_bytes": tok_bytes,
             "tokens": tokens,
             "layers": layers,
@@ -725,12 +772,31 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
     )
 
 
+def _page_geometry(workload: Workload, tok_bytes: int, kv_shard: int
+                   ) -> Tuple[int, int, int, int]:
+    """``(n_kv, head_dim, q_heads, dtype_bytes)`` of one chip's page as the
+    paged kernel sees it (``page_vmem_bytes``'s shape arguments): heads
+    sharded ``kv_shard`` ways; a head-less (MLA latent) cache is one KV
+    "head" as wide as the whole per-token slice, read as both K and V."""
+    dtype_bytes = max(1, workload.dtype_bytes)
+    width = max(1, tok_bytes // dtype_bytes)
+    if workload.kv_heads > 0:
+        n_kv = max(1, workload.kv_heads // kv_shard)
+        head_dim = max(1, width // (2 * n_kv))
+    else:
+        n_kv, head_dim = 1, width
+    q_heads = (max(1, workload.q_heads // kv_shard) if workload.q_heads
+               else n_kv)
+    return n_kv, head_dim, q_heads, dtype_bytes
+
+
 def _tuned_page_tokens(policy: PlanPolicy, tok_bytes: int, tokens: int,
-                       budget: int) -> Tuple[Optional[int], Optional[dict]]:
+                       budget: int, geometry: Tuple[int, int, int, int]
+                       ) -> Tuple[Optional[int], Optional[dict]]:
     """A measured ``page_tokens`` winner for this decode shape, re-checked
-    against the page level's own invariants (sublane alignment, the
-    double-buffered page within the leaf budget); ``(None, None)`` leaves
-    the analytic page standing."""
+    against the page level's own invariants (sublane alignment, the page's
+    VMEM footprint within the leaf budget); ``(None, None)`` leaves the
+    analytic page standing."""
     from repro.tune.cache import bucket_paged, lookup_tuned
 
     spec = policy.spec or _default_spec()
@@ -743,7 +809,7 @@ def _tuned_page_tokens(policy: PlanPolicy, tok_bytes: int, tokens: int,
             and pt % PAGE_ALIGN == 0):
         return None, None
     pt = min(pt, -(-tokens // PAGE_ALIGN) * PAGE_ALIGN)
-    if PAGE_BUFFERING * pt * tok_bytes > budget:
+    if page_vmem_bytes(pt, *geometry) > budget:
         return None, None
     return pt, {
         "speedup": entry.get("speedup", 1.0),

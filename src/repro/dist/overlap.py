@@ -51,7 +51,6 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 #: Collective-matmul modes the overlap layer understands ("gspmd" means
@@ -263,11 +262,11 @@ def make_ag_matmul(mesh: Mesh, axis: str = "model", mode: str = "ring",
             (f_offs[:-1], b_offs[:-1]))
         return compute(lo, hi, acc, f_offs[-1], b_offs[-1])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         ag_local, mesh=mesh,
         in_specs=(P(lead, axis), P(None, axis)),
         out_specs=P(lead, axis),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -369,11 +368,11 @@ def make_ag_matmul_fused(mesh: Mesh, axis: str = "model", mode: str = "ring",
             (f_offs[:-1], b_offs[:-1]))
         return compute(lo, hi, accs, f_offs[-1], b_offs[-1])
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         ag_local, mesh=mesh,
         in_specs=(P(lead, axis),) + (P(None, axis),) * n_out,
         out_specs=tuple(P(lead, axis) for _ in range(n_out)),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit
@@ -485,11 +484,11 @@ def make_rs_matmul(mesh: Mesh, axis: str = "model", mode: str = "ring",
         pf, pb = partials(f_offs[-1], b_offs[-1])
         return jnp.concatenate([acc_f + pf, acc_b + pb], axis=1)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         rs_local, mesh=mesh,
         in_specs=(P(lead, axis), P(axis, None)),
         out_specs=P(out_lead, None),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

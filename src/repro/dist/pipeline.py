@@ -18,7 +18,6 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 PyTree = Any
@@ -98,11 +97,11 @@ def make_pipeline(mesh: Mesh, stage_fn: Callable[[PyTree, jax.Array], jax.Array]
         # Only the tail stage wrote real values; share them with the ring.
         return jax.lax.psum(outputs, axis)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         pipe_local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
 
     @jax.jit

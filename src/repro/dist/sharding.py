@@ -453,6 +453,17 @@ def with_kv_sharding(rules: ShardingRules, kv_shard: int,
     return ShardingRules(dict(rules.param_rules), ar, meta=meta)
 
 
+def with_vmem_limit(rules: ShardingRules,
+                    vmem_limit_bytes: int) -> ShardingRules:
+    """The decode plan's page-level VMEM budget, carried in ``meta`` to the
+    paged-attention kernel traced under these rules (``active_vmem_limit``):
+    the budget the page was sized against is the limit it compiles under."""
+    meta = dict(rules.meta)
+    meta["vmem_limit_bytes"] = int(vmem_limit_bytes)
+    return ShardingRules(dict(rules.param_rules), dict(rules.act_rules),
+                         meta=meta)
+
+
 def with_batch_guard(rules: ShardingRules, mesh, global_batch: int) -> ShardingRules:
     """Trim the batch rule to the mesh axes whose product divides the global
     batch (a batch that cannot split evenly replicates instead of erroring)."""
@@ -575,6 +586,13 @@ def active_overlap() -> Optional[Tuple[Mesh, str, str, Tuple[str, ...]]]:
     batch = tuple(a for a in _rule_axes(rules.act_rules.get("batch"))
                   if a != axis and sizes.get(a, 1) > 1)
     return mesh, axis, mode, batch
+
+
+def active_vmem_limit() -> Optional[int]:
+    """The active rules' planned VMEM limit (``with_vmem_limit``); None
+    outside any ``use_mesh_rules`` context or when the rules carry none."""
+    ctx = _active()
+    return None if ctx is None else ctx[1].meta.get("vmem_limit_bytes")
 
 
 def constrain(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:

@@ -12,6 +12,7 @@ state competes for it.
 from __future__ import annotations
 
 from dataclasses import dataclass, replace
+from functools import lru_cache
 
 from repro.core.hierarchy import MemoryLevel, tpu_hierarchy
 
@@ -93,7 +94,40 @@ TPU_V5P = TPUSpec(
 
 _SPECS = {s.name: s for s in (TPU_V5E, TPU_V4, TPU_V5P)}
 
+#: ``jax.devices()[0].device_kind`` -> spec name.
+_KINDS = {
+    "TPU v5 lite": "tpu_v5e",
+    "TPU v5e": "tpu_v5e",
+    "TPU v4": "tpu_v4",
+    "TPU v5": "tpu_v5p",
+    "TPU v5p": "tpu_v5p",
+}
 
-def chip_spec(name: str = "tpu_v5e", **overrides) -> TPUSpec:
+
+@lru_cache(maxsize=1)
+def _attached_chip() -> TPUSpec:
+    """The spec of the TPU this process runs on, its HBM capped at what the
+    runtime will actually hand out (``memory_stats()["bytes_limit"]``, which
+    is below the nominal size); off-TPU, the v5e target this reproduction
+    plans for."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return TPU_V5E
+    dev = jax.devices()[0]
+    name = _KINDS.get(dev.device_kind)
+    if name is None:
+        raise ValueError(f"no chip spec for device kind {dev.device_kind!r}; "
+                         f"known: {sorted(_KINDS)}")
     spec = _SPECS[name]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        spec = replace(spec, hbm_bytes=min(spec.hbm_bytes, int(limit)))
+    return spec
+
+
+def chip_spec(name: str = "", **overrides) -> TPUSpec:
+    """A chip's spec by name, or with no name the chip this process runs on
+    (``_attached_chip``); ``overrides`` replace fields (tests shrink VMEM)."""
+    spec = _SPECS[name] if name else _attached_chip()
     return replace(spec, **overrides) if overrides else spec

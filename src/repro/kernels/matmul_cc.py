@@ -28,6 +28,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.autotile import MatmulTilePlan
 from repro.core.plan import leaf_matmul_plan
+from repro.hw.tpu import chip_spec
 
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, gk: int):
@@ -94,8 +95,11 @@ def matmul_cc(
         out_specs=pl.BlockSpec((bm, bn), o_map),
         out_shape=jax.ShapeDtypeStruct((gm * bm, gn * bn), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        # The tiles were planned against the chip's usable VMEM; without
+        # the limit Mosaic holds the kernel to its far smaller default.
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=chip_spec().usable_vmem,
         ) if not interpret else None,
         interpret=interpret,
     )(ap, bp)

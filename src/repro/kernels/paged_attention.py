@@ -68,18 +68,22 @@ def _pa_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # footprint (their DMAs all resolve to the cached null page 0).
     @pl.when(p * page_tokens <= qpos)
     def _accumulate():
-        q = q_ref[0]                               # (H, D)
+        qg = q_ref[0]                              # (KV, G, D)
         k = k_ref[0]                               # (T, KV, D)
         v = v_ref[0]
-        h, d = q.shape
-        g = h // n_kv
+        _, g, d = qg.shape
+        h = n_kv * g
 
         # Grouped GQA contraction: query heads grouped per KV head (the
         # same (kv, g) layout as layers.grouped_attention), never
-        # head-repeated.
-        qg = q.reshape(n_kv, g, d)
+        # head-repeated.  The grouping is done outside the kernel: Mosaic
+        # cannot shape-cast a packed (bf16) (H, D) tile into (KV, 1, D).
+        # Sub-f32 products are exact in the f32 accumulator, and Mosaic
+        # refuses an fp32 contract precision on them, so a caller's
+        # default_matmul_precision only reaches f32 pages.
+        prec = None if k.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
         logits = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
+            qg, k, (((2,), (2,)), ((0,), (1,))), precision=prec,
             preferred_element_type=jnp.float32)     # (KV, G, T)
         logits = logits.reshape(h, page_tokens) * scale
 
@@ -99,7 +103,7 @@ def _pa_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_new = l_prev * corr + jnp.sum(pr, axis=-1)[:, None]
         pv = jax.lax.dot_general(
             pr.reshape(n_kv, g, page_tokens).astype(v.dtype), v,
-            (((2,), (0,)), ((0,), (1,))),
+            (((2,), (0,)), ((0,), (1,))), precision=prec,
             preferred_element_type=jnp.float32)      # (KV, G, D)
         acc_ref[...] = acc_ref[...] * corr + pv.reshape(h, d)
         m_ref[...] = m_new
@@ -120,13 +124,19 @@ def paged_attention(
     window: int = 0,
     page_tokens: Optional[int] = None,
     interpret: Optional[bool] = None,
+    vmem_limit_bytes: Optional[int] = None,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """One decode step of attention against the paged KV pool.
 
     ``page_tokens`` is the plan's page size; when given it is asserted
     against the pool's second dim -- the kernel refuses to stream at any
     granule other than the planned page (the whole point of the plan).
-    Returns ``(S, H, D)``.
+    ``vmem_limit_bytes`` is the VMEM budget the page was planned against
+    (``page_plan()["vmem_limit_bytes"]``); the kernel compiles under it, so
+    a page the plan admits is a page Mosaic admits.  Without it, the
+    budget a default plan gives the attached chip.  ``scale`` defaults to
+    ``1/sqrt(D)``.  Returns ``(S, H, D)``.
     """
     s, h, d = q.shape
     p_total, t, n_kv, _ = k_pages.shape
@@ -136,6 +146,18 @@ def paged_attention(
             f"the kernel block must be the planned page")
     if h % n_kv != 0:
         raise ValueError(f"{h} query heads do not group over {n_kv} KV heads")
+
+    # The pool may store the head dim padded to whole lane rows
+    # (``serve.pages.pool_head_dim``): zero query lanes meet the zero pad,
+    # so logits and the kept output lanes are those of the true head dim.
+    dp = k_pages.shape[-1]
+    if dp != d:
+        out = paged_attention(
+            jnp.pad(q, ((0, 0), (0, 0), (0, dp - d))), k_pages, v_pages,
+            page_table, lengths, window=window, page_tokens=page_tokens,
+            interpret=interpret, vmem_limit_bytes=vmem_limit_bytes,
+            scale=1.0 / math.sqrt(d))
+        return out[..., :d]
 
     # The gathered K/V block is (1, t, n_kv, d): n_kv is its sublane
     # (second-minor) dim, and Mosaic tiles it in groups of 8.  A grouped-GQA
@@ -154,19 +176,26 @@ def paged_attention(
                      ((0, 0), (0, pad), (0, 0), (0, 0)))
         out = paged_attention(qg.reshape(s, kv_pad * g, d), k_pages,
                               v_pages, page_table, lengths, window=window,
-                              page_tokens=page_tokens, interpret=interpret)
+                              page_tokens=page_tokens, interpret=interpret,
+                              vmem_limit_bytes=vmem_limit_bytes, scale=scale)
         return out.reshape(s, kv_pad, g, d)[:, :n_kv].reshape(s, h, d)
 
     n_pages = page_table.shape[1]
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    scale = 1.0 / math.sqrt(d)
+    if vmem_limit_bytes is None and not interpret:
+        from repro.hw.tpu import chip_spec
+
+        vmem_limit_bytes = chip_spec().usable_vmem
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                       # page_table, lengths
         grid=(s, n_pages),
         in_specs=[
-            pl.BlockSpec((1, h, d), lambda si, pi, tbl, ln: (si, 0, 0)),
+            pl.BlockSpec((1, n_kv, h // n_kv, d),
+                         lambda si, pi, tbl, ln: (si, 0, 0, 0)),
             pl.BlockSpec((1, t, n_kv, d),
                          lambda si, pi, tbl, ln: (tbl[si, pi], 0, 0, 0)),
             pl.BlockSpec((1, t, n_kv, d),
@@ -179,18 +208,16 @@ def paged_attention(
             pltpu.VMEM((h, d), jnp.float32),         # output accumulator
         ],
     )
-    # jax 0.4.x names it TPUCompilerParams; newer releases CompilerParams.
-    params_cls = getattr(pltpu, "CompilerParams", None) or \
-        pltpu.TPUCompilerParams
     return pl.pallas_call(
         functools.partial(
             _pa_kernel, page_tokens=t, n_kv=n_kv, n_pages=n_pages,
             window=window, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
-        compiler_params=params_cls(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=int(vmem_limit_bytes),
         ) if not interpret else None,
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pages, v_pages)
+      q.reshape(s, n_kv, h // n_kv, d), k_pages, v_pages)

@@ -44,8 +44,9 @@ def paged_attention_ref(
     s, h, d = q.shape
     kv = k_pages.shape[2]
     g = h // kv
-    k = k_pages[page_table].reshape(s, -1, kv, d)     # (S, NP*T, KV, D)
-    v = v_pages[page_table].reshape(s, -1, kv, d)
+    # A pool may pad the head dim to whole lanes; the pad lanes are zero.
+    k = k_pages[page_table][..., :d].reshape(s, -1, kv, d)   # (S, NP*T, KV, D)
+    v = v_pages[page_table][..., :d].reshape(s, -1, kv, d)
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(s, kv, g, d)
     logits = jnp.einsum("skgd,stkd->skgt", qg, k).astype(jnp.float32) * scale

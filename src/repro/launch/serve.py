@@ -8,7 +8,8 @@ CLI only names the architecture, the prompt mix, the sampling config, and
 page-pool engine whenever the decode plan exposes a page level (and the
 family has a per-slot decode path), falling back to cohort batching.
 ``--prefix {off,radix}`` turns on the cross-request radix prefix cache
-(DESIGN.md §11) in the paged engine.
+(DESIGN.md §11) in the paged engine.  ``--reduced false`` serves the
+architecture at its published widths instead of the smoke-test preset.
 
 ``--cluster N`` serves through ``repro.cluster`` instead of one engine
 (DESIGN.md §12): ``plan_decode(cluster=N)`` grows a DCN level whose
@@ -19,7 +20,7 @@ router (``--policy {free_pages,least_loaded,round_robin}``), and
 decode roles with ring-ordered KV page streaming between them
 (``--cluster`` total must equal P+D); page transfer needs the prompt to
 span at least one planned page, so at reduced scale pass
-``--vmem_kib 16`` to force a small page.
+``--vmem_kib 800`` to force a small page.
 """
 
 from __future__ import annotations
@@ -29,15 +30,16 @@ import time
 
 
 def main(argv=None) -> int:
-    import jax
     import numpy as np
 
     from repro.configs import get_model_config, parse_cli
-    from repro.launch.mesh import make_host_mesh
-    from repro.serve import SamplingConfig, ServeEngine, ServePolicy
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.serve import SamplingConfig
 
     overrides, _ = parse_cli(argv if argv is not None else sys.argv[1:])
     arch = overrides.pop("arch", "llama3.2-1b")
+    reduced = overrides.pop("reduced", "true").lower() in ("1", "true",
+                                                           "yes")
     n_new = int(overrides.pop("tokens", "16"))
     batch = int(overrides.pop("batch", "4"))
     prompt_len = int(overrides.pop("prompt_len", "64"))
@@ -61,7 +63,10 @@ def main(argv=None) -> int:
     show_stats = overrides.pop("stats", "0").lower() in ("1", "true",
                                                          "yes")
 
-    cfg = get_model_config(arch).reduced()
+    enable_compile_cache()
+    cfg = get_model_config(arch)
+    if reduced:
+        cfg = cfg.reduced()
     sampling = SamplingConfig(kind=kind, temperature=temperature,
                               top_k=top_k or (40 if kind == "top_k" else 0),
                               seed=seed)
@@ -75,23 +80,16 @@ def main(argv=None) -> int:
         raise SystemExit(f"--prefix must be off|radix, got {prefix!r}")
     if cluster or disagg:
         return _main_cluster(
-            arch=arch, cfg=cfg, n_new=n_new, batch=batch,
+            arch=arch, cfg=cfg, reduced=reduced, n_new=n_new, batch=batch,
             prompt_len=prompt_len, seed=seed, prefix=prefix or "radix",
             cluster=cluster, disagg=disagg, policy=policy,
             serve_http=serve_http, port=port, transport=transport,
             vmem_kib=vmem_kib, trace_path=trace_path,
             show_stats=show_stats)
-    # "auto" resolves inside ServeEngine against its own decode plan:
-    # paged exactly when the plan exposes a page level and the family has
-    # a per-slot decode path; ``--batching cohort`` keeps the PR 4 engine
-    # as the A/B baseline.
-    engine = ServeEngine(
-        cfg, make_host_mesh(),
-        policy=ServePolicy(max_new_tokens=n_new, max_slots=max(1, batch),
-                           max_len=prompt_len + n_new + 1,
-                           batching=batching, prefill=prefill,
-                           prefix_cache=prefix, sampling=sampling),
-        dtype=jax.numpy.float32)
+    engine = build_engine(cfg, n_new=n_new, batch=batch,
+                          max_len=prompt_len + n_new + 1, batching=batching,
+                          prefill=prefill, prefix=prefix, sampling=sampling,
+                          seed=seed)
 
     rng = np.random.default_rng(seed)
     prompts = []
@@ -147,6 +145,29 @@ def main(argv=None) -> int:
     return 0
 
 
+def build_engine(cfg, *, n_new: int, batch: int, max_len: int,
+                 batching: str = "auto", prefill: str = "chunked",
+                 prefix: str = "off", sampling=None, seed: int = 0):
+    """The engine ``repro-serve`` serves with: float32, on every device of
+    this host, ``batch`` decode slots planned for ``max_len`` tokens each.
+    ``batching="auto"`` resolves inside ``ServeEngine`` against its own
+    decode plan: paged exactly when the plan exposes a page level and the
+    family has a per-slot decode path; "cohort" keeps the PR 4 engine as
+    the A/B baseline."""
+    import jax.numpy as jnp
+
+    from repro.launch.mesh import make_host_mesh
+    from repro.serve import SamplingConfig, ServeEngine, ServePolicy
+
+    return ServeEngine(
+        cfg, make_host_mesh(),
+        policy=ServePolicy(max_new_tokens=n_new, max_slots=max(1, batch),
+                           max_len=max_len, batching=batching,
+                           prefill=prefill, prefix_cache=prefix,
+                           sampling=sampling or SamplingConfig()),
+        dtype=jnp.float32, seed=seed)
+
+
 def _metrics_ticker(registry, interval_s: float):
     """Print the registry snapshot every ``interval_s`` on a daemon
     thread (``--metrics-interval``); returns a stop() callable."""
@@ -169,8 +190,8 @@ def _metrics_ticker(registry, interval_s: float):
     return stop.set
 
 
-def _main_cluster(*, arch, cfg, n_new, batch, prompt_len, seed, prefix,
-                  cluster, disagg, policy, serve_http, port, transport,
+def _main_cluster(*, arch, cfg, reduced, n_new, batch, prompt_len, seed,
+                  prefix, cluster, disagg, policy, serve_http, port, transport,
                   vmem_kib=0, trace_path="", show_stats=False) -> int:
     """``repro-serve --cluster N [--disagg P:D] [--serve]``: the fleet
     width comes from the plan's DCN level, each replica hosts one
@@ -204,8 +225,9 @@ def _main_cluster(*, arch, cfg, n_new, batch, prompt_len, seed, prefix,
         raise SystemExit(
             f"--disagg needs the prompt to span >= 1 planned page, but "
             f"page_tokens={page_tokens} > prompt_len={prompt_len}; "
-            f"raise --prompt_len or shrink the page with --vmem_kib 16")
-    spec = EngineSpec(arch=arch, max_new_tokens=n_new, max_slots=1,
+            f"raise --prompt_len or shrink the page with --vmem_kib 800")
+    spec = EngineSpec(arch=arch, reduced=reduced, max_new_tokens=n_new,
+                      max_slots=1,
                       max_len=prompt_len + n_new + 1,
                       prefix_cache="radix" if prefix == "off" else prefix,
                       chip=chip)

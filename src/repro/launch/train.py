@@ -43,12 +43,12 @@ def main(argv=None) -> int:
     for k, v in list(overrides.items()):
         run = apply_overrides(run, {k: v})
 
-    import jax
-
     from repro.data import DataPipeline, SyntheticLMDataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     from repro.launch.trainer import Trainer
 
+    enable_compile_cache()
     mesh = make_host_mesh()
     print(f"[train] arch={arch} reduced={reduced} mesh={mesh.shape} "
           f"params={cfg.param_count() / 1e6:.1f}M")

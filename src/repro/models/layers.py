@@ -477,6 +477,15 @@ def attention_block(
     return out, new_cache
 
 
+def _to_pool(x: jax.Array, pool: jax.Array) -> jax.Array:
+    """K/V rows ``(..., D)`` as the pool stores them: its dtype, the head
+    dim zero-padded to the pool's (``serve.pages.pool_head_dim``)."""
+    pad = pool.shape[-1] - x.shape[-1]
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.astype(pool.dtype)
+
+
 def paged_attention_block(
     params: dict,
     x: jax.Array,                  # (S, 1, d) -- one decode token per slot
@@ -507,6 +516,7 @@ def paged_attention_block(
     the engine asserts host-side before every decode tick.  Shared pages
     are read-only here: the kernel only ever gathers from them.
     """
+    from repro.dist.sharding import active_vmem_limit
     from repro.kernels.paged_attention import paged_attention
 
     b, s, d = x.shape
@@ -534,12 +544,13 @@ def paged_attention_block(
     # live page and corrupt it.
     page_ids = jnp.where(page_slot < n_logical, page_ids, 0)
     off = pos % t
-    k_pool = k_pool.at[layer, page_ids, off].set(k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, page_ids, off].set(v[:, 0].astype(v_pool.dtype))
+    k_pool = k_pool.at[layer, page_ids, off].set(_to_pool(k[:, 0], k_pool))
+    v_pool = v_pool.at[layer, page_ids, off].set(_to_pool(v[:, 0], v_pool))
 
     out = paged_attention(q[:, 0], k_pool[layer], v_pool[layer], table,
                           pos + 1, window=cfg.sliding_window or 0,
-                          page_tokens=t)
+                          page_tokens=t,
+                          vmem_limit_bytes=active_vmem_limit())
     out = tp_matmul(out.reshape(b, s, h * hd),
                     params["wo"].astype(x.dtype), "row")
     return out, k_pool, v_pool
@@ -576,6 +587,7 @@ def paged_prefill_block(
     chunk runs.  The shared prefix pages are only gathered from, through
     the same ``table_row``.
     """
+    from repro.dist.sharding import active_vmem_limit
     from repro.kernels.paged_attention import paged_attention
 
     b, c, d = x.shape
@@ -599,8 +611,8 @@ def paged_prefill_block(
     page_ids = table_row[jnp.minimum(page_slot, n_logical - 1)]
     page_ids = jnp.where(page_slot < n_logical, page_ids, 0)
     off = positions % t
-    k_pool = k_pool.at[layer, page_ids, off].set(k[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[layer, page_ids, off].set(v[0].astype(v_pool.dtype))
+    k_pool = k_pool.at[layer, page_ids, off].set(_to_pool(k[0], k_pool))
+    v_pool = v_pool.at[layer, page_ids, off].set(_to_pool(v[0], v_pool))
 
     # Each chunk token is a "decode row" over the same table with its own
     # causal length -- the paged kernel's per-row kv_len mask does the
@@ -608,7 +620,8 @@ def paged_prefill_block(
     table = jnp.broadcast_to(table_row[None, :], (c, n_logical))
     out = paged_attention(q[0], k_pool[layer], v_pool[layer], table,
                           positions + 1, window=cfg.sliding_window or 0,
-                          page_tokens=t)
+                          page_tokens=t,
+                          vmem_limit_bytes=active_vmem_limit())
     out = tp_matmul(out.reshape(b, c, h * hd),
                     params["wo"].astype(x.dtype), "row")
     return out, k_pool, v_pool

@@ -186,6 +186,7 @@ def paged_mla_attention_block(
     table: jax.Array,              # (S, NP) int32 page table
 ) -> Tuple[jax.Array, jax.Array]:
     """Per-slot absorbed-form MLA decode against the latent page pool."""
+    from repro.dist.sharding import active_vmem_limit
     from repro.kernels.paged_attention import paged_attention
 
     m = cfg.mla
@@ -202,9 +203,10 @@ def paged_mla_attention_block(
     lat_pool = lat_pool.at[layer, page_ids, off].set(
         lat[:, 0, None, :].astype(lat_pool.dtype))
 
-    o_lat = paged_attention(q_cat[:, 0], lat_pool[layer], lat_pool[layer],
-                            table, pos + 1, window=cfg.sliding_window or 0,
-                            page_tokens=t)[..., : m.kv_lora_rank]
+    o_lat = paged_attention(
+        q_cat[:, 0], lat_pool[layer], lat_pool[layer], table, pos + 1,
+        window=cfg.sliding_window or 0, page_tokens=t,
+        vmem_limit_bytes=active_vmem_limit())[..., : m.kv_lora_rank]
     y = _mla_out(params, o_lat[:, None], x.dtype)
     return y, lat_pool
 
@@ -219,6 +221,7 @@ def paged_mla_prefill_block(
     table_row: jax.Array,          # (NP,) int32 -- ONE slot's page table
 ) -> Tuple[jax.Array, jax.Array]:
     """One prompt chunk's MLA attention, latent rows written into pages."""
+    from repro.dist.sharding import active_vmem_limit
     from repro.kernels.paged_attention import paged_attention
 
     m = cfg.mla
@@ -235,9 +238,9 @@ def paged_mla_prefill_block(
         lat[0, :, None, :].astype(lat_pool.dtype))
 
     table = jnp.broadcast_to(table_row[None, :], (c, n_logical))
-    o_lat = paged_attention(q_cat[0], lat_pool[layer], lat_pool[layer],
-                            table, positions + 1,
-                            window=cfg.sliding_window or 0,
-                            page_tokens=t)[..., : m.kv_lora_rank]
+    o_lat = paged_attention(
+        q_cat[0], lat_pool[layer], lat_pool[layer], table, positions + 1,
+        window=cfg.sliding_window or 0, page_tokens=t,
+        vmem_limit_bytes=active_vmem_limit())[..., : m.kv_lora_rank]
     y = _mla_out(params, o_lat[None], x.dtype)
     return y, lat_pool

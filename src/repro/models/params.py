@@ -14,6 +14,7 @@ for ``lax.scan`` over homogeneous layer blocks.
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -53,7 +54,9 @@ def init_params(specs: PyTree, rng: jax.Array, dtype=jnp.float32) -> PyTree:
     """Initialize real arrays from specs (used by smoke tests / examples)."""
 
     def one(path: str, spec: ParamSpec):
-        key = jax.random.fold_in(rng, hash(path) & 0x7FFFFFFF)
+        # crc32, not hash(): str hashes are salted per process, and one
+        # seed must give the same weights in every process.
+        key = jax.random.fold_in(rng, zlib.crc32(path.encode()) & 0x7FFFFFFF)
         if spec.init == "zeros":
             return jnp.zeros(spec.shape, dtype)
         if spec.init == "ones":

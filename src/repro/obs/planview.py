@@ -11,7 +11,7 @@ budget with the observed peak from the engine's metrics registry:
   VMEM [page]  two rows: the page_table's ``pages_total`` vs the pool's
                peak live pages (the acceptance bound: observed peak
                must land inside the planned pool), and the VMEM budget
-               vs the double-buffered page working set
+               vs the paged kernel's tiled page working set (plan-side)
   leaf [VREG]  realized per-worker partition vs the register budget
                (plan-side -- the leaf has no runtime counter)
 
@@ -83,18 +83,13 @@ def plan_vs_actual(plan, registry, band=DEFAULT_BAND,
             observed = val("pool_peak_pages", val("peak_pages", 0))
             rows.append(_row(lp.level, lp.kind, "pool_pages",
                              predicted, observed, "pages", "runtime", band))
-            # And the working set the page was sized for: the planner
-            # guarantees PAGE_BUFFERING * page_bytes <= VMEM budget.
-            try:
-                from repro.core.plan import PAGE_BUFFERING
-            except ImportError:  # pragma: no cover
-                PAGE_BUFFERING = 2
-            page_bytes = val("page_bytes", None)
-            observed_ws = (PAGE_BUFFERING * page_bytes
-                           if page_bytes else None)
+            # And the working set the page was sized for: the paged
+            # kernel's tiled VMEM footprint of one page, which the planner
+            # keeps within the VMEM budget.
+            observed_ws = (plan.page_plan() or {}).get("vmem_bytes")
             rows.append(_row(lp.level, lp.kind, "vmem_working_set",
                              lp.budget_bytes, observed_ws, "B",
-                             "runtime", band))
+                             "plan", band))
         elif lp.kind == "leaf":
             # No runtime counter at register granularity; the residual
             # is the planner's own realized per-worker partition against

@@ -129,6 +129,7 @@ def plan_decode(
             kv_bytes_per_token=tok_bytes,
             kv_layers=max(1, layers),
             kv_heads=heads,
+            q_heads=cfg.n_heads if tok_bytes else 0,
             max_tokens=max_len,
         ),
         PlanPolicy(spec=spec, n_workers=max(1, cluster or 1)),
@@ -258,6 +259,10 @@ class ServeEngine:
             mesh = make_host_mesh()
         self.cfg = cfg
         self.mesh = mesh
+        # Arrays the engine makes outside its jitted steps (weights, the
+        # page pool, per-tick uploads) are made on the mesh's first device,
+        # so a replica on a one-device mesh keeps everything on its chip.
+        self.device = mesh.devices.flat[0]
         self.policy = policy
         self.dtype = dtype if dtype is not None else jnp.float32
         self._dtype_bytes = jnp.dtype(self.dtype).itemsize
@@ -282,9 +287,11 @@ class ServeEngine:
             self.batching = "cohort"        # no paged decode path: fall back
         from repro.models.model import build_model
         self.model = build_model(cfg, remat="none")
-        self.params = (params if params is not None
-                       else self.model.init(jax.random.PRNGKey(seed),
-                                            dtype=jnp.float32))
+        if params is None:
+            with jax.default_device(self.device):
+                params = self.model.init(jax.random.PRNGKey(seed),
+                                         dtype=jnp.float32)
+        self.params = params
         self._steps_cache: Dict[Any, ServeSteps] = {}
         self._paged_steps_cache: Dict[Any, Any] = {}
         self._paged_session: Optional[_PagedSession] = None
@@ -672,11 +679,14 @@ class ServeEngine:
                 f"entries, got {len(max_new)}")
         if not prompts:
             return []
+        import jax
+
         self._stream_cb = on_token
         try:
-            if self.batching == "paged":
-                return self._generate_paged(prompts, max_new, scfg)
-            return self._generate_cohort(prompts, max_new, scfg)
+            with jax.default_device(self.device):
+                if self.batching == "paged":
+                    return self._generate_paged(prompts, max_new, scfg)
+                return self._generate_cohort(prompts, max_new, scfg)
         finally:
             self._stream_cb = None
             self._stream_ix = {}
@@ -741,10 +751,13 @@ class ServeEngine:
         """Pool geometry from the plan (DESIGN.md §8): the logical table
         width is the plan's per-slot page bound, stretched to the longest
         submitted request; the physical pool is the planned KV budget in
-        pages, capped at what the slots can ever pin (plus the null
+        pages (priced as the pool lays them out, ``pool_page_bytes``),
+        capped at what the slots can ever pin (plus the null
         page).  With the prefix cache on, the cap doubles (still inside
         the budget): cached prefixes occupy pool pages BESIDE the live
         slots' working set, up to ``plan.prefix_budget()``."""
+        from repro.serve.pages import pool_page_bytes
+
         page = self.page
         if page.page_bytes <= 0:          # token-free family (xLSTM)
             return 1, 2
@@ -752,13 +765,15 @@ class ServeEngine:
         need = max(page.pages_for(r.prompt_len + r.max_new + 1)
                    for r in reqs)
         pages_per_slot = max(int(ptab.get("pages_per_slot") or 1), need)
-        budget_pages = max(1, self.scheduler.budget_bytes // page.page_bytes)
+        budget_pages = max(1, self.scheduler.budget_bytes
+                           // pool_page_bytes(self.cfg, page))
         slot_pages = n_slots * pages_per_slot
         extra = 0
         if self.policy.prefix_cache == "radix" and \
                 self.cfg.family in self._prefix_families():
             budget = self.plan.prefix_budget() or 0
-            extra = min(budget // page.page_bytes, slot_pages)
+            extra = min(budget // pool_page_bytes(self.cfg, page),
+                        slot_pages)
         pages_total = 1 + min(budget_pages, slot_pages + extra)
         return pages_per_slot, pages_total
 
@@ -1172,8 +1187,14 @@ class ServeEngine:
                     # reclaim resumes at decode (the tree's reference then
                     # keeps them resident through it).
                     sched.reclaim_window(slot, window)
+                # The final chunk also claims the page of the first decode
+                # position: the slot decodes in this same tick, and a
+                # prompt that ends on a page boundary would otherwise
+                # write that token's K/V through an unallocated (null)
+                # table entry, and lose it.
+                upto = done + c + (1 if done + c >= plen else 0)
                 grew = True
-                while not sched.ensure_capacity(slot, upto=done + c):
+                while not sched.ensure_capacity(slot, upto=upto):
                     if sched.table_full(slot):
                         raise RuntimeError(
                             f"slot {slot}: prompt needs more than the "

@@ -23,10 +23,10 @@ runtimes own placement instead of the caller (Thibault et al.; Rasch's
     at slot granularity.
   * ``init_paged_cache`` / ``reset_slot`` -- the pooled cache pytree the
     paged decode step (``Model.decode_step_paged``) consumes: ``pool``
-    (one shared ``(L, P, T, KV, D)`` buffer per attention-layer group;
-    MLA's is a single ``lat`` latent buffer), ``state`` (per-slot
-    recurrent/conv buffers, batch on axis 1; enc-dec adds per-slot cross
-    K/V), ``table`` (the per-slot page table) and the per-slot position
+    (one shared ``(L, P, T, KV, D)`` buffer per attention-layer group, the
+    head dim padded to whole lane rows; MLA's is a single ``lat`` latent
+    buffer), ``state`` (per-slot recurrent/conv buffers, batch on axis 1;
+    enc-dec adds per-slot cross K/V), ``table`` (the per-slot page table) and the per-slot position
     vector ``pos``.  ``reset_slot`` re-initializes a slot's state rows at
     admission; prompt KV reaches the pages via chunked prefill
     (``Model.prefill_chunk``), never via a post-prefill copy.
@@ -444,6 +444,32 @@ def _n_attn_apps(cfg: ModelConfig) -> int:
     return -(-cfg.n_layers // s.attn_every) if (s and s.attn_every) else 0
 
 
+#: Lane width of a TPU vector register: the minor dim of every VMEM tile.
+POOL_LANES = 128
+
+
+def pool_head_dim(head_dim: int) -> int:
+    """Head dim of a K/V page pool: ``head_dim`` padded to whole lane rows.
+
+    The paged kernel reads pages row-major, head dim on the lanes.  A pool
+    whose head dim is narrower than a lane row would be given another HBM
+    layout by default (tokens on the lanes), and every step would then
+    convert the whole pool to row-major around the kernel: two more
+    copies of the pool in HBM.  Padded, row-major is the default layout
+    and costs what the tiles would pad anyway.  The pad lanes stay zero."""
+    return -(-head_dim // POOL_LANES) * POOL_LANES
+
+
+def pool_page_bytes(cfg: ModelConfig, page: PageSpec) -> int:
+    """HBM bytes of one pool page: ``page.page_bytes`` with the K/V head
+    dim as the pool stores it (``pool_head_dim``; MLA's latent is kept
+    unpadded)."""
+    hd = cfg.head_dim
+    if cfg.mla is not None or not hd:
+        return page.page_bytes
+    return page.page_bytes * pool_head_dim(hd) // hd
+
+
 def init_paged_cache(cfg: ModelConfig, model, n_slots: int, n_pages: int,
                      page_tokens: int, n_logical_pages: int,
                      dtype, enc_len: int = 0) -> PyTree:
@@ -466,10 +492,13 @@ def init_paged_cache(cfg: ModelConfig, model, n_slots: int, n_pages: int,
         raise NotImplementedError(
             f"paged serving is not implemented for family {fam!r}")
     kv, hd = cfg.n_kv_heads, cfg.head_dim
+    pool_hd = pool_head_dim(hd)
 
     def pool_kv(nl):
-        return {"k": jnp.zeros((nl, n_pages, page_tokens, kv, hd), dtype),
-                "v": jnp.zeros((nl, n_pages, page_tokens, kv, hd), dtype)}
+        return {"k": jnp.zeros((nl, n_pages, page_tokens, kv, pool_hd),
+                               dtype),
+                "v": jnp.zeros((nl, n_pages, page_tokens, kv, pool_hd),
+                               dtype)}
 
     cache: Dict[str, Any] = {
         "table": jnp.zeros((n_slots, n_logical_pages), jnp.int32),

@@ -34,6 +34,7 @@ from repro.dist.sharding import (
     use_mesh_rules,
     with_batch_guard,
     with_kv_sharding,
+    with_vmem_limit,
 )
 from repro.launch.specs import (
     batch_logical_axes,
@@ -221,6 +222,9 @@ def make_paged_steps(
     rules = with_batch_guard(rules, mesh, n_slots)
     rules = resolve_collectives(rules, collectives)
     rules = with_kv_sharding(rules, kv_shard if heads_divide else 1)
+    page = decode_plan.page_plan() if decode_plan is not None else None
+    if page and page.get("vmem_limit_bytes"):
+        rules = with_vmem_limit(rules, page["vmem_limit_bytes"])
     model = build_model(cfg, remat="none")
     p_shard = param_shardings(mesh, rules, model.param_specs())
 

@@ -340,9 +340,9 @@ def sweep_paged(max_tokens: int = 256, n_kv: int = 2, group: int = 2,
     ``page_tokens`` and each candidate re-lays the pool at that granule."""
     from repro.core.plan import (
         PAGE_ALIGN,
-        PAGE_BUFFERING,
         PlanPolicy,
         Workload,
+        page_vmem_bytes,
         plan_run,
     )
 
@@ -352,6 +352,7 @@ def sweep_paged(max_tokens: int = 256, n_kv: int = 2, group: int = 2,
     hp = plan_run(
         spec.hierarchy(),
         Workload(kv_bytes_per_token=tok_bytes, kv_layers=1, kv_heads=n_kv,
+                 q_heads=n_kv * group, dtype_bytes=dtype_bytes,
                  max_tokens=max_tokens),
         PlanPolicy(spec=spec, vmem_fraction=vmem_fraction, use_tuned=False))
     page = hp.page_plan()
@@ -362,7 +363,8 @@ def sweep_paged(max_tokens: int = 256, n_kv: int = 2, group: int = 2,
                min(cap, _round_up(center_pt * 2, PAGE_ALIGN))}
     raw = [{"page_tokens": pt} for pt in sorted(raw_pts)]
     fitting, rejected = _dedup_fitting(
-        raw, lambda b: PAGE_BUFFERING * b["page_tokens"] * tok_bytes, budget)
+        raw, lambda b: page_vmem_bytes(b["page_tokens"], n_kv, head_dim,
+                                       n_kv * group, dtype_bytes), budget)
     result = SweepResult(
         kernel="paged_attention",
         bucket=bucket_paged(tok_bytes, max_tokens),

@@ -1,21 +1,8 @@
-"""Test bootstrap: make ``repro`` importable without the PYTHONPATH=src hack
-and provide a minimal in-repo ``hypothesis`` stand-in when the real package
-is absent (the container has no network; hard constraint: no pip installs).
+"""Test bootstrap: make ``repro`` importable without the PYTHONPATH=src
+hack, and keep the planner's measured artifacts out of the tests."""
 
-The stub implements exactly the surface this suite uses -- ``given``,
-``settings(max_examples, deadline)``, ``strategies.integers``,
-``strategies.sampled_from``, ``strategies.booleans``, ``strategies.floats``
--- as a deterministic pseudo-random sweep.  It trades hypothesis's shrinking
-and example database for zero dependencies; failures print the drawn
-arguments so a repro is one copy-paste away.
-"""
-
-import functools
-import inspect
 import os
-import random
 import sys
-import types
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "src")
@@ -34,75 +21,3 @@ if _SRC not in sys.path:
 # tmp files, which takes precedence over this default.
 os.environ.setdefault("REPRO_CALIBRATION", os.devnull + ".absent")
 os.environ.setdefault("REPRO_TUNING", os.devnull + ".absent")
-
-
-def _install_hypothesis_stub() -> None:
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    class _Strategy:
-        def __init__(self, draw):
-            self.draw = draw
-
-    def integers(min_value, max_value):
-        return _Strategy(lambda r: r.randint(min_value, max_value))
-
-    def sampled_from(elements):
-        elements = list(elements)
-        return _Strategy(lambda r: r.choice(elements))
-
-    def booleans():
-        return _Strategy(lambda r: r.random() < 0.5)
-
-    def floats(min_value=0.0, max_value=1.0, **_kw):
-        return _Strategy(lambda r: r.uniform(min_value, max_value))
-
-    def settings(max_examples=50, deadline=None, **_kw):
-        def deco(fn):
-            fn._stub_max_examples = max_examples
-            return fn
-        return deco
-
-    def given(**strategies):
-        def deco(fn):
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                n = getattr(wrapper, "_stub_max_examples", 50)
-                rng = random.Random(0xC0DE)
-                for i in range(n):
-                    drawn = {k: s.draw(rng) for k, s in strategies.items()}
-                    try:
-                        fn(*args, **kwargs, **drawn)
-                    except Exception:
-                        print(f"[hypothesis-stub] falsifying example "
-                              f"#{i}: {drawn}", file=sys.stderr)
-                        raise
-            # Hide the drawn parameters from pytest's fixture resolution:
-            # only non-strategy params (self, real fixtures) remain visible.
-            sig = inspect.signature(fn)
-            kept = [p for name, p in sig.parameters.items()
-                    if name not in strategies]
-            wrapper.__signature__ = sig.replace(parameters=kept)
-            if hasattr(wrapper, "__wrapped__"):
-                del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    mod = types.ModuleType("hypothesis")
-    st = types.ModuleType("hypothesis.strategies")
-    st.integers = integers
-    st.sampled_from = sampled_from
-    st.booleans = booleans
-    st.floats = floats
-    mod.given = given
-    mod.settings = settings
-    mod.strategies = st
-    mod.__stub__ = True
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = st
-
-
-_install_hypothesis_stub()

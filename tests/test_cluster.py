@@ -48,7 +48,7 @@ FOUR_FAMILIES = ["llama3.2-1b", "mixtral-8x7b", "zamba2-1.2b", "xlstm-1.3b"]
 
 #: Tiny forced VMEM so the planned page is small and several pages per
 #: sequence are exercised (the same knob the paged/prefix tests use).
-SMALL = dict(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+SMALL = dict(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
 
 
 def _stats(free, drained=(), queued=None):

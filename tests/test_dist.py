@@ -20,6 +20,7 @@ def run_with_devices(n: int, body: str) -> str:
         import jax.numpy as jnp
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", script],
@@ -33,7 +34,7 @@ def run_with_devices(n: int, body: str) -> str:
 def test_ag_matmul_ring_matches_reference():
     run_with_devices(4, """
         from repro.dist.overlap import make_ag_matmul
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         fn = make_ag_matmul(mesh, axis="model")
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (64, 32), jnp.float32)
@@ -48,7 +49,7 @@ def test_ag_matmul_ring_matches_reference():
 def test_rs_matmul_ring_matches_reference():
     run_with_devices(4, """
         from repro.dist.overlap import make_rs_matmul
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         fn = make_rs_matmul(mesh, axis="model")
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (64, 32), jnp.float32)
@@ -63,7 +64,7 @@ def test_rs_matmul_ring_matches_reference():
 def test_pipeline_gpipe_matches_sequential():
     run_with_devices(4, """
         from repro.dist.pipeline import make_pipeline
-        mesh = jax.make_mesh((4,), ("pod",))
+        mesh = make_mesh((4,), ("pod",))
 
         def stage_fn(params, x):
             return jnp.tanh(x @ params["w"])
@@ -91,7 +92,7 @@ def test_train_step_loss_decreases_on_mesh():
         from repro.launch.trainer import make_train_step, init_sharded_state
         from repro.data import SyntheticLMDataset
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         shape = ShapeConfig("tiny", seq_len=32, global_batch=8, kind="train")
         train = TrainConfig(learning_rate=3e-3, warmup_steps=5,
@@ -126,14 +127,14 @@ def test_elastic_checkpoint_restore_across_meshes():
         shape = ShapeConfig("tiny", seq_len=16, global_batch=4, kind="train")
         train = TrainConfig(remat="none")
 
-        mesh1 = jax.make_mesh((4, 1), ("data", "model"))
+        mesh1 = make_mesh((4, 1), ("data", "model"))
         ts1 = make_train_step(cfg, shape, mesh1, train)
         params, opt = init_sharded_state(ts1, mesh1, 0, train)
         mgr = CheckpointManager({tmp!r}, keep=2)
         mgr.save(7, (params, opt), blocking=True)
 
         # "Relaunch" on a different topology.
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh2 = make_mesh((2, 2), ("data", "model"))
         ts2 = make_train_step(cfg, shape, mesh2, train)
         p2, o2 = init_sharded_state(ts2, mesh2, 1, train)
 
@@ -158,7 +159,7 @@ def test_collectives_equivalence_gspmd_ring_serpentine():
     4-device host mesh, for both the all-gather and reduce-scatter rings."""
     run_with_devices(4, """
         from repro.dist.overlap import make_ag_matmul, make_rs_matmul
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         key = jax.random.PRNGKey(0)
         x = jax.random.normal(key, (64, 32), jnp.float32)
         w = jax.random.normal(jax.random.fold_in(key, 1), (32, 48), jnp.float32)
@@ -177,7 +178,7 @@ def test_serpentine_odd_size_error_messages():
     error must name the mode and the fix."""
     run_with_devices(4, """
         from repro.dist.overlap import make_ag_matmul, make_rs_matmul
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         ag = make_ag_matmul(mesh, axis="model", mode="serpentine")
         try:
             ag(jnp.zeros((8, 12)), jnp.zeros((12, 8)))   # kb = 3, odd
@@ -206,7 +207,7 @@ def test_model_forward_equivalence_under_overlap_collectives():
         from repro.launch.specs import make_batch
         from repro.models.model import build_model
 
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = make_mesh((1, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         model = build_model(cfg, remat="none")
         params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
@@ -238,7 +239,7 @@ def test_train_step_with_serpentine_collectives():
         from repro.data import SyntheticLMDataset
         from repro.launch.trainer import make_train_step, init_sharded_state
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         shape = ShapeConfig("tiny", seq_len=32, global_batch=8, kind="train")
         train = TrainConfig(learning_rate=3e-3, warmup_steps=5,

@@ -26,7 +26,7 @@ from repro.dist.sharding import (
     with_batch_guard,
 )
 
-MESH = AbstractMesh((("data", 4), ("model", 4)))
+MESH = AbstractMesh((4, 4), ("data", "model"))
 
 
 def _hier(hbm_gb: float):
@@ -187,7 +187,7 @@ class TestXLSTMStateSharding:
     rules must fall back to SUB-AXIS sharding: heads unsharded, the
     per-head state inner dim (mLSTM dh=1024, sLSTM d/H=512) carries TP."""
 
-    PROD = AbstractMesh((("data", 16), ("model", 16)))
+    PROD = AbstractMesh((16, 16), ("data", "model"))
 
     def test_state_inner_carries_tp_when_heads_cannot(self):
         cfg = get_model_config("xlstm-1.3b")
@@ -222,7 +222,7 @@ class TestXLSTMStateSharding:
 
     def test_small_model_axis_still_shards_heads(self):
         cfg = get_model_config("xlstm-1.3b")         # 4 state heads
-        mesh = AbstractMesh((("data", 4), ("model", 4)))
+        mesh = AbstractMesh((4, 4), ("data", "model"))
         rules = arch_rules(cfg, mesh, state_bytes_per_param=2)
         assert rules.act_rules["state_heads"] == "model"
         assert rules.act_rules["state_inner"] is None

@@ -39,10 +39,11 @@ from repro.obs import (Counter, Gauge, Histogram, MetricsView, Registry,
                        RingLog, Tracer, merge_events, plan_vs_actual,
                        prometheus_lines, validate_events)
 from repro.serve import ServeEngine, ServePolicy
+from repro.serve.pages import pool_page_bytes
 
 FOUR_FAMILIES = ["llama3.2-1b", "mixtral-8x7b", "zamba2-1.2b", "xlstm-1.3b"]
 
-SMALL = dict(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+SMALL = dict(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
 
 
 # ---------------------------------------------------------------------------
@@ -322,7 +323,8 @@ def test_tokens_never_negative_under_recompute_preemption():
     engine = ServeEngine(
         cfg, mesh,
         policy=ServePolicy(max_len=4 * t, max_slots=2, batching="paged",
-                           kv_budget_bytes=probe.page.page_bytes * 3),
+                           kv_budget_bytes=3 * pool_page_bytes(cfg,
+                                                               probe.page)),
         spec=chip_spec(**SMALL))
     rng = np.random.default_rng(0)
     deep, shallow = 3 * t - 8, 2 * t - 8

@@ -213,7 +213,7 @@ class TestWrapperEquivalence:
 
 
 class TestRulesConsumeThePlan:
-    MESH = AbstractMesh((("data", 4), ("model", 4)))
+    MESH = AbstractMesh((4, 4), ("data", "model"))
 
     def _hier(self, hbm_gb):
         return tpu_hierarchy(hbm_bytes=int(hbm_gb * GiB),
@@ -265,7 +265,7 @@ class TestPipelineMapsOntoDCN:
         from repro.dist.pipeline import make_pipeline
 
         hp = plan_run(_hier(hosts=2, chips=4), Workload(state_bytes=65 * GiB))
-        mesh = AbstractMesh((("pod", 4),))
+        mesh = AbstractMesh((4,), ("pod",))
         with pytest.raises(ValueError, match="DCN sub-plan prescribes 2"):
             make_pipeline(mesh, lambda p, x: x, axis="pod", plan=hp)
 

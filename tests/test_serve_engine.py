@@ -95,7 +95,7 @@ def test_page_growth_and_eviction_under_pressure():
 
     cfg = get_model_config("llama3.2-1b").reduced()
     tok_bytes, _, _ = kv_token_bytes(cfg, 4)
-    small = chip_spec(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+    small = chip_spec(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
     mesh = make_host_mesh()
     rng = np.random.default_rng(0)
 
@@ -129,7 +129,7 @@ def test_compaction_frees_finished_slots_at_growth():
 
     cfg = get_model_config("llama3.2-1b").reduced()
     mesh = make_host_mesh()
-    small = chip_spec(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+    small = chip_spec(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 256, 8, dtype=np.int32) for _ in range(2)]
 

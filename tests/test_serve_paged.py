@@ -25,6 +25,7 @@ from repro.configs import get_model_config
 from repro.hw.tpu import chip_spec
 from repro.launch.mesh import make_host_mesh
 from repro.serve import ServeEngine, ServePolicy
+from repro.serve.pages import pool_page_bytes
 
 #: One arch per served family, as in test_serve_engine: dense attention,
 #: MoE (sliding-window), hybrid SSM (Mamba2 + shared attn), xLSTM.
@@ -32,7 +33,7 @@ FOUR_FAMILIES = ["llama3.2-1b", "mixtral-8x7b", "zamba2-1.2b", "xlstm-1.3b"]
 
 #: Tiny forced VMEM so the planned page is small and page bookkeeping is
 #: actually exercised (several pages per sequence).
-SMALL = dict(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+SMALL = dict(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
 
 #: Mixed prompt lengths AND mixed max_new: the early finisher shares a
 #: cohort with a long request (cohort mode drags its dead slot until the
@@ -70,6 +71,25 @@ def test_paged_token_identical_and_higher_utilization(arch):
         paged.metrics["pages_released"], arch
 
 
+def test_prompt_ending_on_a_page_boundary():
+    """A prompt of whole pages decodes its first token into a page the
+    last prefill chunk claimed: that token's K/V must not be written
+    through an unallocated table entry and lost.  One slot serves both
+    requests in turn, so the second also reuses the first's pages."""
+    cfg, cohort = _engines("llama3.2-1b", "cohort")
+    t = cohort.page.page_tokens
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, cfg.vocab_size, n, dtype=np.int32)
+               for n in (t, 2 * t)]
+    news = [t // 2, t // 2]
+    paged = ServeEngine(cfg, make_host_mesh(),
+                        policy=ServePolicy(max_new_tokens=4, max_len=64,
+                                           max_slots=1, batching="paged"),
+                        spec=chip_spec(**SMALL))
+    assert cohort.generate(prompts, max_new_tokens=news) == \
+        paged.generate(prompts, max_new_tokens=news)
+
+
 def test_pool_geometry_comes_from_the_plan():
     """Page size + pool geometry verbatim from ``plan_run``'s page level:
     the pool pages are ``page_plan()["page_tokens"]`` tokens, the table
@@ -105,7 +125,7 @@ def test_paged_eviction_under_tiny_pool():
                         policy=ServePolicy(max_len=128, batching="paged"),
                         spec=chip_spec(**SMALL))
     t = probe.page.page_tokens
-    budget = probe.page.page_bytes * 3       # 3 usable pages for 2 slots
+    budget = pool_page_bytes(cfg, probe.page) * 3   # 3 usable pages, 2 slots
     engine = ServeEngine(
         cfg, mesh,
         policy=ServePolicy(max_len=4 * t, max_slots=2, batching="paged",
@@ -144,7 +164,8 @@ def test_paged_eviction_is_lossless():
     tight = ServeEngine(
         cfg, mesh,
         policy=ServePolicy(max_len=4 * t, max_slots=2, batching="paged",
-                           kv_budget_bytes=probe.page.page_bytes * 3),
+                           kv_budget_bytes=3 * pool_page_bytes(cfg,
+                                                               probe.page)),
         spec=chip_spec(**SMALL))
     outs = tight.generate(prompts, max_new_tokens=news)
     assert tight.metrics["evictions"] >= 1
@@ -176,7 +197,8 @@ def test_paged_stall_preserves_recurrent_state():
     tight = ServeEngine(
         cfg, mesh,
         policy=ServePolicy(max_len=4 * t, max_slots=2, batching="paged",
-                           kv_budget_bytes=probe.page.page_bytes * 3),
+                           kv_budget_bytes=3 * pool_page_bytes(cfg,
+                                                               probe.page)),
         spec=chip_spec(**SMALL))
     outs = tight.generate(prompts, max_new_tokens=news)
     assert tight.metrics["stalls"] >= 1     # the pressure path ran
@@ -191,7 +213,7 @@ def test_paged_window_overflow_prompt_and_reclaim():
     cfg = get_model_config("mixtral-8x7b").reduced()
     assert cfg.sliding_window
     mesh = make_host_mesh()
-    spec = chip_spec(vmem_bytes=8 << 10, vmem_reserved_bytes=0)
+    spec = chip_spec(vmem_bytes=700 << 10, vmem_reserved_bytes=0)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, cfg.vocab_size,
                             cfg.sliding_window + 8, dtype=np.int32)]
@@ -216,13 +238,13 @@ def test_windowed_prompt_billed_for_resident_window_only():
     never allocated), so the admission demand is ~window, not prompt."""
     cfg = get_model_config("mixtral-8x7b").reduced()
     mesh = make_host_mesh()
-    spec = chip_spec(vmem_bytes=8 << 10, vmem_reserved_bytes=0)
+    spec = chip_spec(vmem_bytes=700 << 10, vmem_reserved_bytes=0)
     probe = ServeEngine(cfg, mesh,
                         policy=ServePolicy(max_len=160, batching="paged"),
                         spec=spec)
     t = probe.page.page_tokens
     plen = 4 * cfg.sliding_window            # prompt >> window
-    budget = probe.page.page_bytes * (cfg.sliding_window // t + 2)
+    budget = pool_page_bytes(cfg, probe.page) * (cfg.sliding_window // t + 2)
     rng = np.random.default_rng(2)
     prompts = [rng.integers(0, cfg.vocab_size, plen, dtype=np.int32)]
     pol = dict(max_len=plen + 16, max_slots=1, kv_budget_bytes=budget)

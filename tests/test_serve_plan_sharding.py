@@ -17,6 +17,7 @@ def _run(body: str) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
@@ -39,7 +40,7 @@ def test_plan_kv_shard_realized_in_cache_sharding():
         from repro.launch.specs import make_batch
         from repro.serve import make_serve_steps, plan_decode
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=4, n_heads=4)
         shape = ShapeConfig("d", 64, 4, "decode")
@@ -83,7 +84,7 @@ def test_plan_replicated_kv_when_memory_fits():
         from repro.configs.base import ShapeConfig
         from repro.serve import make_serve_steps, plan_decode
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=2, n_heads=4)
         shape = ShapeConfig("d", 64, 4, "decode")
@@ -108,7 +109,7 @@ def test_plan_page_matches_engine_capacity():
         from repro.serve import ServeEngine, ServePolicy
 
         cfg = get_model_config("llama3.2-1b").reduced()
-        small = chip_spec(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+        small = chip_spec(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
         engine = ServeEngine(cfg, make_host_mesh(),
                              policy=ServePolicy(max_new_tokens=12,
                                                 max_len=64),

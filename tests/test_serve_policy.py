@@ -16,6 +16,7 @@ def _run(body: str) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         import jax.numpy as jnp
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     proc = subprocess.run(
         [sys.executable, "-c", script], capture_output=True, text=True,
@@ -32,7 +33,7 @@ def test_auto_policy_head_shards_when_divisible():
         from repro.launch.trainer import make_serve_steps
 
         import dataclasses
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=4, n_heads=4)  # 4 % 4 == 0
         shape = ShapeConfig("d", 64, 4, "decode")
@@ -52,7 +53,7 @@ def test_auto_policy_seq_shards_when_heads_dont_divide():
         from repro.configs.base import ShapeConfig
         from repro.launch.trainer import make_serve_steps
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=2, n_heads=4)  # 2 % 4 != 0
         shape = ShapeConfig("d", 64, 4, "decode")
@@ -74,7 +75,7 @@ def test_auto_policy_decode_step_runs_and_matches_baseline():
         from repro.launch.trainer import make_serve_steps
         from repro.launch.specs import make_batch
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = get_model_config("llama3.2-1b").reduced()
         cfg = dataclasses.replace(cfg, n_kv_heads=2, n_heads=4)
         shape = ShapeConfig("d", 64, 4, "decode")

@@ -36,7 +36,7 @@ from repro.serve import ServeEngine, ServePolicy
 
 #: Tiny forced VMEM so the planned page (= prefill chunk) is small and the
 #: chunk loop actually runs several iterations per prompt.
-SMALL = dict(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+SMALL = dict(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
 
 #: Every family with a paged decode path (serve.pages.PAGED_FAMILIES).
 PAGED_ARCHS = [

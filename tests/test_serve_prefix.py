@@ -37,7 +37,7 @@ from repro.serve import ServeEngine, ServePolicy
 
 #: Tiny forced VMEM so the planned page is small and sharing/CoW is
 #: exercised with short prompts (as in test_serve_paged).
-SMALL = dict(vmem_bytes=16 << 10, vmem_reserved_bytes=0)
+SMALL = dict(vmem_bytes=800 << 10, vmem_reserved_bytes=0)
 
 #: One arch per served family: dense, MoE (sliding-window), hybrid SSM,
 #: xLSTM (token-free -- state snapshots only).
