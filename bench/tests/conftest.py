@@ -1,0 +1,12 @@
+"""Make ``bench`` and ``repro`` importable, and keep the tests on the CPU
+(the paged kernel then runs in interpret mode)."""
+
+import os
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+for p in (str(ROOT / "src"), str(ROOT)):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
