@@ -219,5 +219,6 @@ def paged_attention(
             vmem_limit_bytes=int(vmem_limit_bytes),
         ) if not interpret else None,
         interpret=interpret,
+        name="paged_attention",
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       q.reshape(s, n_kv, h // n_kv, d), k_pages, v_pages)

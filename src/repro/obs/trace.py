@@ -12,21 +12,52 @@ Events live in a bounded ring (old events drop, ``dropped`` counts
 them) -- the same policy ``RingLog`` applies to the engine's legacy
 ``interleave``/``token_times`` metrics, which previously grew without
 limit over an engine's lifetime.
+
+Each ``span()`` also opens a JAX profiler annotation ``serve.<name>``,
+so under ``jax.profiler`` the same phases sit on the host plane of the
+profiler's trace, beside the device's operations.  The annotation
+follows the profiler's own on/off (about a microsecond when it is off);
+the ring follows ``enabled``.  The Chrome export's ``clock_sync``
+metadata event holds the epoch on both the monotonic and the wall
+clock (``time.time_ns``, which the profiler's host events use), so a
+``--trace`` file can be lined up with a profiler trace.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, Iterable, Iterator, List, Optional
 
 # Single timeline zero for every tracer in this process: thread-transport
 # replicas and the router all subtract the same epoch, so their ``ts``
 # values interleave correctly in one exported trace.
 _EPOCH = time.monotonic()
+_EPOCH_NS = time.time_ns()
+
+
+@functools.lru_cache(maxsize=None)
+def _profiler():
+    """``jax.profiler``, imported on first use so that ``repro.obs``
+    imports without JAX; None where JAX is not installed."""
+    try:
+        from jax import profiler
+    except ImportError:
+        return None
+    return profiler
+
+
+def _annotation(name: str, step: Optional[int]):
+    prof = _profiler()
+    if prof is None:
+        return nullcontext()
+    if step is None:
+        return prof.TraceAnnotation(name)
+    return prof.StepTraceAnnotation(name, step_num=step)
 
 
 class RingLog:
@@ -142,10 +173,15 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, tid: int = 0,
-             args: Optional[Dict[str, Any]] = None):
+             args: Optional[Dict[str, Any]] = None,
+             step: Optional[int] = None):
+        """A ``B``/``E`` pair around the block, and the profiler
+        annotation ``serve.<name>`` -- a step annotation numbered
+        ``step`` where one is given."""
         self.begin(name, tid, args)
         try:
-            yield self
+            with _annotation(f"serve.{name}", step):
+                yield self
         finally:
             self.end(name, tid)
 
@@ -182,7 +218,10 @@ class Tracer:
 
     def metadata_events(self) -> List[Dict[str, Any]]:
         return [{"name": "process_name", "ph": "M", "pid": self.pid,
-                 "tid": 0, "args": {"name": self.process_name}}]
+                 "tid": 0, "args": {"name": self.process_name}},
+                {"name": "clock_sync", "ph": "M", "pid": self.pid,
+                 "tid": 0, "args": {"epoch_monotonic_s": _EPOCH,
+                                    "epoch_time_ns": _EPOCH_NS}}]
 
     def chrome_events(self, last: Optional[int] = None) -> List[Dict[str, Any]]:
         return self.metadata_events() + self.export_events(last)

@@ -1087,264 +1087,286 @@ class ServeEngine:
             clear_slot(victim)
             self.metrics["evictions"] += 1
 
+        tick = 0
         while sched.has_work():
-            progressed = False
-            # Capacity FIRST, oldest request first: growth claims its pages
-            # before admission can hand the last free ones to a new request
-            # whose just-run prefill an older grower would immediately
-            # evict.  An older slot preempts strictly-younger victims
-            # (recompute); a slot with no younger victim STALLS this tick
-            # (pages pinned, decode skipped) -- the oldest slot always
-            # progresses, so no eviction ping-pong.  Prefilling slots
-            # claim capacity in the chunk phase instead (ahead of their
-            # chunk front, not their decode position).
-            stalled: set = set()
-            for i in sorted(sched.active(),
-                            key=lambda j: sched.slots[j].rid):
-                if sched.slots[i] is None or i in prefills:
-                    continue                  # evicted by an older grower
-                while not sched.ensure_capacity(i):
-                    if sched.table_full(i):
-                        stalled.add(i)    # eviction cannot widen the table
-                        self.metrics["stalls"] += 1
-                        break
-                    victim = sched.victim(i)
-                    if victim is None:
-                        if len(sched.active()) == 1:
-                            raise RuntimeError(
-                                f"page pool ({pool.pages_total - 1} pages)"
-                                f" cannot hold one growing sequence; "
-                                f"raise kv_budget_bytes")
-                        stalled.add(i)
-                        self.metrics["stalls"] += 1
-                        break
-                    preempt(victim)
+            with self.tracer.span("tick", step=tick):
+                progressed = False
+                # Capacity FIRST, oldest request first: growth claims its pages
+                # before admission can hand the last free ones to a new request
+                # whose just-run prefill an older grower would immediately
+                # evict.  An older slot preempts strictly-younger victims
+                # (recompute); a slot with no younger victim STALLS this tick
+                # (pages pinned, decode skipped) -- the oldest slot always
+                # progresses, so no eviction ping-pong.  Prefilling slots
+                # claim capacity in the chunk phase instead (ahead of their
+                # chunk front, not their decode position).
+                stalled: set = set()
+                with self.tracer.span("schedule"):
+                    for i in sorted(sched.active(),
+                                    key=lambda j: sched.slots[j].rid):
+                        if sched.slots[i] is None or i in prefills:
+                            continue        # evicted by an older grower
+                        while not sched.ensure_capacity(i):
+                            if sched.table_full(i):
+                                # Eviction cannot widen the table.
+                                stalled.add(i)
+                                self.metrics["stalls"] += 1
+                                break
+                            victim = sched.victim(i)
+                            if victim is None:
+                                if len(sched.active()) == 1:
+                                    raise RuntimeError(
+                                        f"page pool ({pool.pages_total - 1} "
+                                        f"pages) cannot hold one growing "
+                                        f"sequence; raise kv_budget_bytes")
+                                stalled.add(i)
+                                self.metrics["stalls"] += 1
+                                break
+                            preempt(victim)
 
-            # Admission: a slot + its first page (token-free: none); the
-            # prompt itself streams in below, one chunk per tick, straight
-            # into pool pages.  Enc-dec runs its encoder once here and
-            # installs the cross K/V into the slot's state rows.  A prefix
-            # hit starts the slot at ``hit.tokens`` with the shared pages
-            # already in its table: CoW-copy the divergent page, restore
-            # the state snapshot, and prefill covers only the suffix.
-            for slot, req, pages, hit in sched.admit(chunked=True):
-                now = time.monotonic()
-                t_sub = self._t_submit.get(req.rid, t0)
-                self.tracer.complete("queue_wait", t_sub, now,
-                                     tid=req.rid + 1,
-                                     args={"rid": req.rid, "slot": slot})
-                self.obs.observe("queue_wait_s", now - t_sub)
-                cache = reset_slot(self.cfg, self.model, cache, slot,
-                                   cross_kv=self._encode_req(steps, req),
-                                   enc_len=req.group[1])
-                table_np[slot] = 0
-                push_table(slot)
-                pos_np[slot] = sched.slots[slot].pos
-                next_np[slot, 0] = 0
-                prefills[slot] = sched.slots[slot].pos
-                if hit is not None:
-                    cache = self._apply_prefix_hit(cache, slot, hit)
-                    self.metrics["prefix_hits"] += 1
-                    self.metrics["prefix_hit_tokens"] += hit.tokens
-                    self.metrics["pages_saved"] += \
-                        hit.tokens // page.page_tokens
-                    if hit.cow is not None:
-                        self.metrics["cow_copies"] += 1
-                        self.tracer.instant(
-                            "cow_copy", tid=req.rid + 1,
+                    # Admission: a slot + its first page (token-free: none);
+                    # the prompt itself streams in below, one chunk per tick,
+                    # straight into pool pages.  Enc-dec runs its encoder
+                    # once here and installs the cross K/V into the slot's
+                    # state rows.  A prefix hit starts the slot at
+                    # ``hit.tokens`` with the shared pages already in its
+                    # table: CoW-copy the divergent page, restore the state
+                    # snapshot, and prefill covers only the suffix.
+                    for slot, req, pages, hit in sched.admit(chunked=True):
+                        now = time.monotonic()
+                        t_sub = self._t_submit.get(req.rid, t0)
+                        self.tracer.complete(
+                            "queue_wait", t_sub, now, tid=req.rid + 1,
+                            args={"rid": req.rid, "slot": slot})
+                        self.obs.observe("queue_wait_s", now - t_sub)
+                        cache = reset_slot(
+                            self.cfg, self.model, cache, slot,
+                            cross_kv=self._encode_req(steps, req),
+                            enc_len=req.group[1])
+                        table_np[slot] = 0
+                        push_table(slot)
+                        pos_np[slot] = sched.slots[slot].pos
+                        next_np[slot, 0] = 0
+                        prefills[slot] = sched.slots[slot].pos
+                        if hit is not None:
+                            cache = self._apply_prefix_hit(cache, slot, hit)
+                            self.metrics["prefix_hits"] += 1
+                            self.metrics["prefix_hit_tokens"] += hit.tokens
+                            self.metrics["pages_saved"] += \
+                                hit.tokens // page.page_tokens
+                            if hit.cow is not None:
+                                self.metrics["cow_copies"] += 1
+                                self.tracer.instant(
+                                    "cow_copy", tid=req.rid + 1,
+                                    args={"rid": req.rid, "slot": slot,
+                                          "src": hit.cow[0],
+                                          "dst": hit.cow[1]})
+                        elif prefix is not None:
+                            self.metrics["prefix_misses"] += 1
+                        # A backfill is a NEW request taking a previously used
+                        # slot mid-flight; a preempted request's own recompute
+                        # re-admission is not one.
+                        if slot in ever_occupied and req.rid not in requeued:
+                            self.metrics["backfills"] += 1
+                        requeued.discard(req.rid)
+                        ever_occupied.add(slot)
+                        progressed = True
+
+                # Chunk phase: one chunk per prefilling slot per tick, BEFORE
+                # the decode step -- a prefilling slot rides through the decode
+                # batch (its garbage write at the chunk front is overwritten by
+                # the next chunk; its recurrent state is restored below), so
+                # chunks and decode ticks interleave instead of serializing.
+                for slot in sorted(prefills):
+                    s = sched.slots[slot]
+                    if s is None or slot not in prefills:
+                        continue            # preempted by a sibling chunk
+                    req, plen = s.req, s.req.prompt_len
+                    done = prefills[slot]
+                    # A prefix hit can start mid-page; the first suffix chunk
+                    # realigns to the chunk grid (cold starts reduce to the
+                    # plain ``min(chunk, remaining)``).
+                    c = plen - done if chunk_tokens <= 0 else \
+                        min(chunk_tokens - done % chunk_tokens, plen - done)
+                    if window and prefix is None:
+                        # Behind the front.  With the prefix cache on, prompt
+                        # pages must SURVIVE to insertion below -- window
+                        # reclaim resumes at decode (the tree's reference then
+                        # keeps them resident through it).
+                        sched.reclaim_window(slot, window)
+                    # The final chunk also claims the page of the first decode
+                    # position: the slot decodes in this same tick, and a
+                    # prompt that ends on a page boundary would otherwise
+                    # write that token's K/V through an unallocated (null)
+                    # table entry, and lose it.
+                    upto = done + c + (1 if done + c >= plen else 0)
+                    grew = True
+                    while not sched.ensure_capacity(slot, upto=upto):
+                        if sched.table_full(slot):
+                            raise RuntimeError(
+                                f"slot {slot}: prompt needs more than the "
+                                f"{pages_per_slot}-page table")
+                        victim = sched.victim(slot)
+                        if victim is None:
+                            if len(sched.active()) == 1:
+                                raise RuntimeError(
+                                    f"page pool ({pool.pages_total - 1} pages)"
+                                    f" cannot hold one prefill chunk; "
+                                    f"raise kv_budget_bytes")
+                            stalled.add(slot)
+                            self.metrics["stalls"] += 1
+                            grew = False
+                            break
+                        preempt(victim)
+                    if not grew:
+                        continue                  # retry the chunk next tick
+                    with self.tracer.span(
+                            "prefill_chunk", tid=req.rid + 1,
                             args={"rid": req.rid, "slot": slot,
-                                  "src": hit.cow[0], "dst": hit.cow[1]})
-                elif prefix is not None:
-                    self.metrics["prefix_misses"] += 1
-                # A backfill is a NEW request taking a previously used
-                # slot mid-flight; a preempted request's own recompute
-                # re-admission is not one.
-                if slot in ever_occupied and req.rid not in requeued:
-                    self.metrics["backfills"] += 1
-                requeued.discard(req.rid)
-                ever_occupied.add(slot)
-                progressed = True
+                                  "done": done, "tokens": c}):
+                        peak_pages = max(peak_pages, pool.used_pages)
+                        if page.page_bytes > 0:
+                            # CoW safety: every page this chunk writes must
+                            # be PRIVATE (refcount 1) -- shared prefix pages
+                            # sit strictly below the suffix front and are
+                            # mapped read-only (see
+                            # models/layers.paged_attention_block).
+                            for j in range(done // page.page_tokens,
+                                           -(-(done + c) // page.page_tokens)):
+                                p = s.pages[j] if j < len(s.pages) else None
+                                assert p is None or pool.refcount(p) == 1, \
+                                    f"chunk would write shared page {p} (rc=" \
+                                    f"{pool.refcount(p)})"
+                        push_table(slot)
+                        cache["table"] = jnp.asarray(table_np)
+                        toks = jnp.asarray(
+                            np.asarray(req.features["tokens"][done:done + c],
+                                       np.int32))[None]
+                        logits, cache = steps.prefill_chunk(
+                            self.params, cache, toks, jnp.int32(done),
+                            jnp.int32(slot))
+                        self.metrics["prefill_chunks"] += 1
+                        self.metrics["prefill_tokens"] += c
+                        trace.append(("chunk", slot, done, c))
+                        done += c
+                        prefills[slot] = done
+                        s.pos = done
+                        pos_np[slot] = done
+                        progressed = True
+                        if prefix is not None and prefix.has_state and \
+                                cache.get("state") and \
+                                done % page.page_tokens == 0:
+                            # Page-boundary state snapshot (host copy): the
+                            # radix node for this block restores it on a
+                            # future hit.
+                            chunk_snaps.setdefault(slot, {})[done] = \
+                                jax.tree.map(
+                                    lambda a: (np.asarray(a[:, slot])
+                                               if a.ndim >= 2
+                                               else np.asarray(a[slot])),
+                                    cache["state"])
+                        if done >= plen:
+                            del prefills[slot]
+                            if prefix is not None:
+                                self.metrics["prefix_nodes_inserted"] += \
+                                    prefix.insert(
+                                        np.asarray(req.features["tokens"]),
+                                        list(s.pages),
+                                        snaps=chunk_snaps.pop(slot, None))
+                            with self.tracer.span("readback",
+                                                  tid=req.rid + 1):
+                                tok = int(np.asarray(sample(
+                                    logits, scfg,
+                                    step_key(scfg, step))).reshape(-1)[0])
+                            step += 1
+                            with self.tracer.span("emit",
+                                                  tid=req.rid + 1):
+                                emit_token(slot, req.rid, req.max_new, tok)
 
-            # Chunk phase: one chunk per prefilling slot per tick, BEFORE
-            # the decode step -- a prefilling slot rides through the decode
-            # batch (its garbage write at the chunk front is overwritten by
-            # the next chunk; its recurrent state is restored below), so
-            # chunks and decode ticks interleave instead of serializing.
-            for slot in sorted(prefills):
-                s = sched.slots[slot]
-                if s is None or slot not in prefills:
-                    continue                  # preempted by a sibling chunk
-                req, plen = s.req, s.req.prompt_len
-                done = prefills[slot]
-                # A prefix hit can start mid-page; the first suffix chunk
-                # realigns to the chunk grid (cold starts reduce to the
-                # plain ``min(chunk, remaining)``).
-                c = plen - done if chunk_tokens <= 0 else \
-                    min(chunk_tokens - done % chunk_tokens, plen - done)
-                if window and prefix is None:
-                    # Behind the front.  With the prefix cache on, prompt
-                    # pages must SURVIVE to insertion below -- window
-                    # reclaim resumes at decode (the tree's reference then
-                    # keeps them resident through it).
-                    sched.reclaim_window(slot, window)
-                # The final chunk also claims the page of the first decode
-                # position: the slot decodes in this same tick, and a
-                # prompt that ends on a page boundary would otherwise
-                # write that token's K/V through an unallocated (null)
-                # table entry, and lose it.
-                upto = done + c + (1 if done + c >= plen else 0)
-                grew = True
-                while not sched.ensure_capacity(slot, upto=upto):
-                    if sched.table_full(slot):
-                        raise RuntimeError(
-                            f"slot {slot}: prompt needs more than the "
-                            f"{pages_per_slot}-page table")
-                    victim = sched.victim(slot)
-                    if victim is None:
-                        if len(sched.active()) == 1:
-                            raise RuntimeError(
-                                f"page pool ({pool.pages_total - 1} pages)"
-                                f" cannot hold one prefill chunk; "
-                                f"raise kv_budget_bytes")
-                        stalled.add(slot)
-                        self.metrics["stalls"] += 1
-                        grew = False
-                        break
-                    preempt(victim)
-                if not grew:
-                    continue                  # retry the chunk next tick
-                peak_pages = max(peak_pages, pool.used_pages)
-                if page.page_bytes > 0:
-                    # CoW safety: every page this chunk writes must be
-                    # PRIVATE (refcount 1) -- shared prefix pages sit
-                    # strictly below the suffix front and are mapped
-                    # read-only (see models/layers.paged_attention_block).
-                    for j in range(done // page.page_tokens,
-                                   -(-(done + c) // page.page_tokens)):
+                active = [i for i in sched.active()
+                          if i not in stalled and i not in prefills]
+                if active and page.page_bytes > 0 and prefix is not None:
+                    # CoW safety for decode writes: the write position's page
+                    # is always private (shared prefix pages end strictly
+                    # below the suffix, and positions only grow).
+                    for i in active:
+                        s = sched.slots[i]
+                        j = s.pos // page.page_tokens
                         p = s.pages[j] if j < len(s.pages) else None
                         assert p is None or pool.refcount(p) == 1, \
-                            f"chunk would write shared page {p} (rc=" \
+                            f"decode would write shared page {p} (rc=" \
                             f"{pool.refcount(p)})"
-                push_table(slot)
-                cache["table"] = jnp.asarray(table_np)
-                toks = jnp.asarray(
-                    np.asarray(req.features["tokens"][done:done + c],
-                               np.int32))[None]
-                tc0 = time.monotonic()
-                logits, cache = steps.prefill_chunk(
-                    self.params, cache, toks, jnp.int32(done),
-                    jnp.int32(slot))
-                self.tracer.complete(
-                    "prefill_chunk", tc0, time.monotonic(),
-                    tid=req.rid + 1,
-                    args={"rid": req.rid, "slot": slot, "done": done,
-                          "tokens": c})
-                self.metrics["prefill_chunks"] += 1
-                self.metrics["prefill_tokens"] += c
-                trace.append(("chunk", slot, done, c))
-                done += c
-                prefills[slot] = done
-                s.pos = done
-                pos_np[slot] = done
-                progressed = True
-                if prefix is not None and prefix.has_state and \
-                        cache.get("state") and \
-                        done % page.page_tokens == 0:
-                    # Page-boundary state snapshot (host copy): the radix
-                    # node for this block restores it on a future hit.
-                    chunk_snaps.setdefault(slot, {})[done] = jax.tree.map(
-                        lambda a: (np.asarray(a[:, slot]) if a.ndim >= 2
-                                   else np.asarray(a[slot])),
-                        cache["state"])
-                if done >= plen:
-                    del prefills[slot]
-                    if prefix is not None:
-                        self.metrics["prefix_nodes_inserted"] += \
-                            prefix.insert(
-                                np.asarray(req.features["tokens"]),
-                                list(s.pages),
-                                snaps=chunk_snaps.pop(slot, None))
-                    tok = int(np.asarray(
-                        sample(logits, scfg,
-                               step_key(scfg, step))).reshape(-1)[0])
-                    step += 1
-                    emit_token(slot, req.rid, req.max_new, tok)
+                if active:
+                    with self.tracer.span("decode_tick",
+                                          args={"active": len(active)}):
+                        # Refresh the device-side page tables from the
+                        # scheduler: growth appended pages, reclaim nulled
+                        # out-of-window ones.
+                        for i in sched.active():
+                            push_table(i)
+                        cache["table"] = jnp.asarray(table_np)
+                        cache["pos"] = jnp.asarray(pos_np)
+                        # Stalled AND prefilling slots still ride through
+                        # the decode batch.  Their KV writes land on the null
+                        # page or at the chunk front (overwritten by the next
+                        # chunk), but RECURRENT state (Mamba/xLSTM) would
+                        # advance on the discarded tick and corrupt the slot
+                        # on resume -- so snapshot their state rows and
+                        # restore them after the step.
+                        frozen = sorted(
+                            {i for i in set(stalled) | set(prefills)
+                             if sched.slots[i] is not None})
+                        snapshot = None
+                        if frozen and cache.get("state"):
+                            sl = jnp.asarray(frozen)
+                            # Slot axis is 1 for layer-stacked buffers, 0 for
+                            # per-slot vectors (enc-dec's ``enc_len``).
+                            snapshot = jax.tree.map(
+                                lambda a: a[:, sl] if a.ndim >= 2 else a[sl],
+                                cache["state"])
+                        logits, cache = steps.decode(
+                            self.params, cache,
+                            {"tokens": jnp.asarray(next_np)})
+                        if snapshot is not None:
+                            cache["state"] = jax.tree.map(
+                                lambda ns, snap: (ns.at[:, sl].set(snap)
+                                                  if ns.ndim >= 2
+                                                  else ns.at[sl].set(snap)),
+                                cache["state"], snapshot)
+                        trace.append(("decode", tuple(active)))
+                        with self.tracer.span("readback"):
+                            toks = np.asarray(sample(
+                                logits, scfg,
+                                step_key(scfg, step))).reshape(-1)
+                        step += 1
+                        self.metrics["decode_steps"] += 1
+                        self.metrics["slot_steps"] += n_slots
+                        self.metrics["active_slot_steps"] += len(active)
+                    with self.tracer.span("emit"):
+                        for i in active:
+                            s = sched.slots[i]
+                            s.pos += 1
+                            pos_np[i] = s.pos
+                            emit_token(i, s.rid, s.req.max_new, int(toks[i]))
+                    progressed = True
 
-            active = [i for i in sched.active()
-                      if i not in stalled and i not in prefills]
-            if active and page.page_bytes > 0 and prefix is not None:
-                # CoW safety for decode writes: the write position's page
-                # is always private (shared prefix pages end strictly
-                # below the suffix, and positions only grow).
-                for i in active:
-                    s = sched.slots[i]
-                    j = s.pos // page.page_tokens
-                    p = s.pages[j] if j < len(s.pages) else None
-                    assert p is None or pool.refcount(p) == 1, \
-                        f"decode would write shared page {p} (rc=" \
-                        f"{pool.refcount(p)})"
-            if active:
-                # Refresh the device-side page tables from the scheduler:
-                # growth appended pages, reclaim nulled out-of-window ones.
-                for i in sched.active():
-                    push_table(i)
-                cache["table"] = jnp.asarray(table_np)
-                cache["pos"] = jnp.asarray(pos_np)
-                # Stalled AND prefilling slots still ride through the
-                # decode batch.  Their KV writes land on the null page or
-                # at the chunk front (overwritten by the next chunk), but
-                # RECURRENT state (Mamba/xLSTM) would advance on the
-                # discarded tick and corrupt the slot on resume -- so
-                # snapshot their state rows and restore them after the
-                # step.
-                frozen = sorted({i for i in (set(stalled) | set(prefills))
-                                 if sched.slots[i] is not None})
-                snapshot = None
-                if frozen and cache.get("state"):
-                    sl = jnp.asarray(frozen)
-                    # Slot axis is 1 for layer-stacked buffers, 0 for
-                    # per-slot vectors (enc-dec's ``enc_len``).
-                    snapshot = jax.tree.map(
-                        lambda a: a[:, sl] if a.ndim >= 2 else a[sl],
-                        cache["state"])
-                td0 = time.monotonic()
-                logits, cache = steps.decode(
-                    self.params, cache, {"tokens": jnp.asarray(next_np)})
-                if snapshot is not None:
-                    cache["state"] = jax.tree.map(
-                        lambda ns, snap: (ns.at[:, sl].set(snap)
-                                          if ns.ndim >= 2
-                                          else ns.at[sl].set(snap)),
-                        cache["state"], snapshot)
-                self.tracer.complete("decode_tick", td0, time.monotonic(),
-                                     tid=0, args={"active": len(active)})
-                trace.append(("decode", tuple(active)))
-                toks = np.asarray(
-                    sample(logits, scfg, step_key(scfg, step))).reshape(-1)
-                step += 1
-                self.metrics["decode_steps"] += 1
-                self.metrics["slot_steps"] += n_slots
-                self.metrics["active_slot_steps"] += len(active)
-                for i in active:
-                    s = sched.slots[i]
-                    s.pos += 1
-                    pos_np[i] = s.pos
-                    emit_token(i, s.rid, s.req.max_new, int(toks[i]))
-                progressed = True
-
-            peak_pages = max(peak_pages, pool.used_pages)
-            if prefix is None:
-                assert pool.used_pages == sched.used_pages_by_slots(), \
-                    "page pool out of sync with the slot tables"
-            else:
-                # Shared pages carry one refcount per mapping: every slot
-                # table entry plus every radix-tree node.
-                assert pool.total_refs == sched.used_pages_by_slots() \
-                    + prefix.n_pages, "refcount ledger out of sync"
-            assert pool.pages_allocated - pool.pages_released == \
-                pool.used_pages, "page accounting leak"
-            if not progressed:
-                raise RuntimeError("scheduler stalled with pending work")
+                with self.tracer.span("check"):
+                    peak_pages = max(peak_pages, pool.used_pages)
+                    if prefix is None:
+                        assert pool.used_pages == \
+                            sched.used_pages_by_slots(), \
+                            "page pool out of sync with the slot tables"
+                    else:
+                        # Shared pages carry one refcount per mapping: every
+                        # slot table entry plus every radix-tree node.
+                        assert pool.total_refs == sched.used_pages_by_slots() \
+                            + prefix.n_pages, "refcount ledger out of sync"
+                    assert pool.pages_allocated - pool.pages_released == \
+                        pool.used_pages, "page accounting leak"
+                    if not progressed:
+                        raise RuntimeError(
+                            "scheduler stalled with pending work")
+            tick += 1
 
         self.metrics["peak_resident_bytes"] = peak_pages * page.page_bytes
         self.metrics["peak_pages"] = peak_pages

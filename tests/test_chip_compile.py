@@ -79,7 +79,10 @@ def test_paged_attention_compiles_at_planned_page(one_chip, arch, dtype):
                          ((SLOTS, cfg.n_heads, cfg.head_dim), dtype),
                          (pool, dtype), (pool, dtype),
                          ((SLOTS, n_pages), jnp.int32), ((SLOTS,), jnp.int32))
-    assert "tpu_custom_call" in text
+    # The kernel is the Mosaic custom call, and keeps its name beside it.
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all("%paged_attention" in ln for ln in kernels)
 
 
 def test_matmul_cc_compiles_at_planned_tile(one_chip):
