@@ -74,6 +74,9 @@ __all__ = [
     "Workload",
     "leaf_matmul_plan",
     "page_vmem_bytes",
+    "prefill_block_q",
+    "prefill_kv_block",
+    "prefill_page_vmem_bytes",
     "plan_run",
     "quantize_divisor",
 ]
@@ -112,6 +115,14 @@ PAGE_ALIGN = 8
 #: current page's attention math), so two pages are resident at once.
 PAGE_BUFFERING = 2
 
+#: What the v5e compiler spills to VMEM in the prefill kernel (its
+#: register allocator's spill slots), fitted to its own accounting: the
+#: larger of a part that grows with the page (bytes per page token, by
+#: K/V dtype width; it holds at short tiles) and 1.5 times the f32
+#: ``(kv, rows, page)`` scores (1.41 measured; it holds at tall tiles).
+PREFILL_PAGE_SPILL = {2: 48 << 10, 4: 66 << 10}
+PREFILL_SCORE_SPILL = 1.5
+
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
@@ -147,6 +158,63 @@ def page_vmem_bytes(page_tokens: int, n_kv: int, head_dim: int,
     scratch = 3 * _round_up(h, 8) * lanes * 4
     scores = 4 * kv * _round_up(g, 8) * _round_up(page_tokens, lane) * 4
     return pages + relaid + products + q_out + scratch + scores
+
+
+def prefill_kv_block(n_kv: int, dtype_bytes: int) -> int:
+    """KV heads per grid step of the prefill kernel: one sublane tile of
+    the dtype where the (8-padded) head count splits into them, else all.
+    Every head block reads its own slice of each page, so the bytes a tile
+    reads do not change, and the VMEM a query row costs shrinks with the
+    block -- which buys a taller query tile (``prefill_block_q``)."""
+    sub = max(8, (4 // max(1, dtype_bytes)) * 8)
+    kv = _round_up(max(1, n_kv), 8)
+    return sub if kv % sub == 0 else kv
+
+
+def prefill_page_vmem_bytes(block_q: int, page_tokens: int, n_kv: int,
+                            head_dim: int, q_heads: int, dtype_bytes: int,
+                            lane: int = 128) -> int:
+    """VMEM that ``kernels.paged_attention.paged_prefill_attention`` holds
+    to stream one page past a tile of ``block_q`` query rows, for one
+    block of ``prefill_kv_block`` KV heads, counted in Mosaic's tiled
+    layout as ``page_vmem_bytes`` counts the decode kernel's: the
+    double-buffered K/V page blocks (the heads on the sublanes, padded to
+    the dtype's granule), the double-buffered query and output tiles
+    ``(kv, block_q * group, lanes)``, the f32 running max/sum (lane-padded)
+    and accumulator scratch, and the compiler's spill
+    (``PREFILL_PAGE_SPILL``, ``PREFILL_SCORE_SPILL``).  Checked against
+    the compiler's own VMEM accounting in ``tests/test_chip_compile.py``."""
+    sub = max(8, (4 // max(1, dtype_bytes)) * 8)
+    g = max(1, -(-max(1, q_heads) // max(1, n_kv)))
+    kv = prefill_kv_block(n_kv, dtype_bytes)
+    rows = _round_up(block_q * g, 8)
+    lanes = _round_up(head_dim, lane)
+    pages = PAGE_BUFFERING * 2 * page_tokens * _round_up(kv, sub) * lanes \
+        * dtype_bytes
+    q_out = PAGE_BUFFERING * 2 * kv * _round_up(rows, sub) * lanes \
+        * dtype_bytes
+    scratch = kv * rows * (lanes + 2 * lane) * 4
+    scores = kv * rows * _round_up(page_tokens, lane) * 4
+    spill = max(PREFILL_PAGE_SPILL.get(dtype_bytes, PREFILL_PAGE_SPILL[2])
+                * page_tokens, int(PREFILL_SCORE_SPILL * scores))
+    return pages + q_out + scratch + spill
+
+
+def prefill_block_q(chunk: int, page_tokens: int, n_kv: int, head_dim: int,
+                    q_heads: int, dtype_bytes: int, budget: int) -> int:
+    """The prefill kernel's query tile: the largest multiple of
+    ``PAGE_ALIGN`` rows, at most the chunk (rounded up to the alignment),
+    whose working set (``prefill_page_vmem_bytes``) fits ``budget`` -- the
+    page level's search applied to the query side.  The alignment floor
+    when not even that fits."""
+    geometry = (page_tokens, n_kv, head_dim, q_heads, dtype_bytes)
+    best = PAGE_ALIGN
+    for bq in range(PAGE_ALIGN, _round_up(max(1, chunk), PAGE_ALIGN) + 1,
+                    PAGE_ALIGN):
+        if prefill_page_vmem_bytes(bq, *geometry) > budget:
+            break
+        best = bq
+    return best
 
 
 # ---------------------------------------------------------------------------
@@ -730,6 +798,10 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
     page_bytes = page_tokens * tok_bytes
     vmem_bytes = page_vmem_bytes(page_tokens, *geometry)
     n_pages = -(-tokens // page_tokens)
+    # The prefill kernel streams the same page past a tile of a chunk's
+    # query rows (a chunk is one page of tokens, ``chunk_tokens``): the
+    # tallest tile that fits the same budget.
+    block_q = prefill_block_q(page_tokens, page_tokens, *geometry, budget)
     # Pool geometry (the paged engine's bounds, DESIGN.md §8): one logical
     # page costs ``page_tokens x kv_bytes_per_token`` GLOBAL bytes; the
     # innermost mesh level's per-chip HBM leftover after the replicated
@@ -751,6 +823,9 @@ def _plan_page_level(level: MemoryLevel, workload: Workload,
             "page_bytes": page_bytes,
             "vmem_bytes": vmem_bytes,
             "vmem_limit_bytes": budget,
+            "prefill_block_q": block_q,
+            "prefill_vmem_bytes": prefill_page_vmem_bytes(
+                block_q, page_tokens, *geometry),
             "tok_bytes": tok_bytes,
             "tokens": tokens,
             "layers": layers,

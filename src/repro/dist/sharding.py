@@ -453,13 +453,17 @@ def with_kv_sharding(rules: ShardingRules, kv_shard: int,
     return ShardingRules(dict(rules.param_rules), ar, meta=meta)
 
 
-def with_vmem_limit(rules: ShardingRules,
-                    vmem_limit_bytes: int) -> ShardingRules:
+def with_vmem_limit(rules: ShardingRules, vmem_limit_bytes: int,
+                    prefill_block_q: int = 0) -> ShardingRules:
     """The decode plan's page-level VMEM budget, carried in ``meta`` to the
-    paged-attention kernel traced under these rules (``active_vmem_limit``):
-    the budget the page was sized against is the limit it compiles under."""
+    paged-attention kernels traced under these rules (``active_vmem_limit``):
+    the budget the page was sized against is the limit they compile under.
+    ``prefill_block_q`` is the prefill kernel's query tile planned against
+    the same budget (``active_prefill_block_q``)."""
     meta = dict(rules.meta)
     meta["vmem_limit_bytes"] = int(vmem_limit_bytes)
+    if prefill_block_q:
+        meta["prefill_block_q"] = int(prefill_block_q)
     return ShardingRules(dict(rules.param_rules), dict(rules.act_rules),
                          meta=meta)
 
@@ -593,6 +597,14 @@ def active_vmem_limit() -> Optional[int]:
     outside any ``use_mesh_rules`` context or when the rules carry none."""
     ctx = _active()
     return None if ctx is None else ctx[1].meta.get("vmem_limit_bytes")
+
+
+def active_prefill_block_q() -> Optional[int]:
+    """The active rules' planned prefill query tile (``with_vmem_limit``);
+    None outside any ``use_mesh_rules`` context or when the rules carry
+    none."""
+    ctx = _active()
+    return None if ctx is None else ctx[1].meta.get("prefill_block_q")
 
 
 def constrain(x: jax.Array, axes: Sequence[Optional[str]]) -> jax.Array:

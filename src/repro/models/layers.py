@@ -574,9 +574,10 @@ def paged_prefill_block(
     ``positions``, scatters K/V through the slot's ``table_row`` (page
     ``positions // T`` at offset ``positions % T`` -- the pages the
     scheduler allocated ahead of the chunk front), and attends causally
-    over everything written so far by treating each query token as a
-    decode row of length ``position + 1`` in the Pallas paged kernel.
-    Zero post-prefill copies: the pages ARE the prefill destination.
+    over everything written so far in the chunk-wide Pallas prefill
+    kernel, which reads each live page once per tile of the planned
+    ``prefill_block_q`` query rows.  Zero post-prefill copies: the pages
+    ARE the prefill destination.
     Returns ``(out (1, C, d), k_pool, v_pool)``.
 
     Write contract under prefix sharing (DESIGN.md §11): on a radix
@@ -587,8 +588,8 @@ def paged_prefill_block(
     chunk runs.  The shared prefix pages are only gathered from, through
     the same ``table_row``.
     """
-    from repro.dist.sharding import active_vmem_limit
-    from repro.kernels.paged_attention import paged_attention
+    from repro.dist.sharding import active_prefill_block_q, active_vmem_limit
+    from repro.kernels.paged_attention import paged_prefill_attention
 
     b, c, d = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -614,14 +615,11 @@ def paged_prefill_block(
     k_pool = k_pool.at[layer, page_ids, off].set(_to_pool(k[0], k_pool))
     v_pool = v_pool.at[layer, page_ids, off].set(_to_pool(v[0], v_pool))
 
-    # Each chunk token is a "decode row" over the same table with its own
-    # causal length -- the paged kernel's per-row kv_len mask does the
-    # intra-chunk causal masking for free.
-    table = jnp.broadcast_to(table_row[None, :], (c, n_logical))
-    out = paged_attention(q[0], k_pool[layer], v_pool[layer], table,
-                          positions + 1, window=cfg.sliding_window or 0,
-                          page_tokens=t,
-                          vmem_limit_bytes=active_vmem_limit())
+    out = paged_prefill_attention(
+        q[0], k_pool[layer], v_pool[layer], table_row, positions[0],
+        window=cfg.sliding_window or 0, page_tokens=t,
+        block_q=active_prefill_block_q(),
+        vmem_limit_bytes=active_vmem_limit())
     out = tp_matmul(out.reshape(b, c, h * hd),
                     params["wo"].astype(x.dtype), "row")
     return out, k_pool, v_pool

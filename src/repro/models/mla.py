@@ -220,9 +220,11 @@ def paged_mla_prefill_block(
     layer,
     table_row: jax.Array,          # (NP,) int32 -- ONE slot's page table
 ) -> Tuple[jax.Array, jax.Array]:
-    """One prompt chunk's MLA attention, latent rows written into pages."""
-    from repro.dist.sharding import active_vmem_limit
-    from repro.kernels.paged_attention import paged_attention
+    """One prompt chunk's MLA attention, latent rows written into pages
+    and read back by the chunk-wide prefill kernel as one KV "head" that
+    is both K and V."""
+    from repro.dist.sharding import active_prefill_block_q, active_vmem_limit
+    from repro.kernels.paged_attention import paged_prefill_attention
 
     m = cfg.mla
     b, c, d = x.shape
@@ -237,10 +239,10 @@ def paged_mla_prefill_block(
     lat_pool = lat_pool.at[layer, page_ids, off].set(
         lat[0, :, None, :].astype(lat_pool.dtype))
 
-    table = jnp.broadcast_to(table_row[None, :], (c, n_logical))
-    o_lat = paged_attention(
-        q_cat[0], lat_pool[layer], lat_pool[layer], table, positions + 1,
+    o_lat = paged_prefill_attention(
+        q_cat[0], lat_pool[layer], lat_pool[layer], table_row, positions[0],
         window=cfg.sliding_window or 0, page_tokens=t,
+        block_q=active_prefill_block_q(),
         vmem_limit_bytes=active_vmem_limit())[..., : m.kv_lora_rank]
     y = _mla_out(params, o_lat[None], x.dtype)
     return y, lat_pool

@@ -316,7 +316,8 @@ class ServeEngine:
         for name in ("tokens", "tokens_recomputed", "decode_steps",
                      "cohorts", "evictions", "slot_steps",
                      "active_slot_steps", "backfills", "stalls",
-                     "prefill_chunks", "prefill_tokens", "prefix_hits",
+                     "prefill_chunks", "prefill_kv_page_reads",
+                     "prefill_tokens", "prefix_hits",
                      "prefix_misses", "prefix_hit_tokens", "pages_saved",
                      "cow_copies", "prefix_nodes_inserted",
                      "interleave_dropped", "token_times_dropped"):
@@ -952,6 +953,7 @@ class ServeEngine:
         import jax
         import jax.numpy as jnp
 
+        from repro.kernels.paged_attention import prefill_page_reads
         from repro.serve.pages import (
             PagePool,
             PagedScheduler,
@@ -1000,6 +1002,8 @@ class ServeEngine:
         chunk_tokens = self.plan.chunk_tokens() or page.page_tokens
         if self.policy.prefill == "monolithic" or chunk_tokens <= 0:
             chunk_tokens = 0                  # whole prompt per chunk
+        # The prefill kernel's query tile, as the steps hand it over.
+        prefill_bq = (self.plan.page_plan() or {}).get("prefill_block_q")
         trace = RingLog(maxlen=self.LOG_CAPACITY)
         self.metrics["interleave"] = trace
 
@@ -1247,6 +1251,13 @@ class ServeEngine:
                             jnp.int32(slot))
                         self.metrics["prefill_chunks"] += 1
                         self.metrics["prefill_tokens"] += c
+                        if cache.get("pool"):
+                            # K/V pages the chunk kernel fetched for one
+                            # layer: its re-read factor over the live pages.
+                            self.metrics["prefill_kv_page_reads"] += \
+                                prefill_page_reads(
+                                    done, c, prefill_bq, page.page_tokens,
+                                    window or 0, pages_per_slot)
                         trace.append(("chunk", slot, done, c))
                         done += c
                         prefills[slot] = done

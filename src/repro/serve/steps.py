@@ -224,7 +224,8 @@ def make_paged_steps(
     rules = with_kv_sharding(rules, kv_shard if heads_divide else 1)
     page = decode_plan.page_plan() if decode_plan is not None else None
     if page and page.get("vmem_limit_bytes"):
-        rules = with_vmem_limit(rules, page["vmem_limit_bytes"])
+        rules = with_vmem_limit(rules, page["vmem_limit_bytes"],
+                                page.get("prefill_block_q", 0))
     model = build_model(cfg, remat="none")
     p_shard = param_shardings(mesh, rules, model.param_specs())
 

@@ -23,7 +23,10 @@ from repro.configs import get_model_config
 from repro.core.plan import leaf_matmul_plan
 from repro.hw.tpu import chip_spec
 from repro.kernels.matmul_cc import matmul_cc
-from repro.kernels.paged_attention import paged_attention
+from repro.kernels.paged_attention import (
+    paged_attention,
+    paged_prefill_attention,
+)
 from repro.serve.engine import plan_decode
 from repro.serve.pages import pool_head_dim
 
@@ -83,6 +86,41 @@ def test_paged_attention_compiles_at_planned_page(one_chip, arch, dtype):
     kernels = [ln for ln in text.splitlines()
                if 'custom_call_target="tpu_custom_call"' in ln]
     assert kernels and all("%paged_attention" in ln for ln in kernels)
+
+
+@pytest.mark.parametrize("arch,max_len,slots", [
+    ("stablelm-1.6b", 4096, 16),         # MHA, head dim 64 padded to 128
+    ("deepseek-coder-33b", 16384, 10),   # GQA 56/8 at 128, a 1176-token page
+])
+def test_paged_prefill_compiles_at_planned_tile(one_chip, arch, max_len,
+                                                slots):
+    """The chunk kernel compiles for a full chunk at the planner's
+    ``prefill_block_q``, within the VMEM the planner counted for it
+    (``prefill_page_vmem_bytes``)."""
+    cfg = get_model_config(arch)
+    plan = plan_decode(cfg, AbstractMesh((1, 1), ("data", "model")),
+                       max_len=max_len, batch=slots, dtype_bytes=2,
+                       spec=chip_spec("tpu_v5e"))
+    page = plan.page_plan()
+    pt, bq = page["page_tokens"], page["prefill_block_q"]
+    assert bq % 8 == 0 and 8 <= bq <= pt
+    assert page["prefill_vmem_bytes"] <= page["vmem_limit_bytes"]
+    n_pages = -(-max_len // pt)
+    pool = (1 + n_pages, pt, cfg.n_kv_heads, pool_head_dim(cfg.head_dim))
+
+    def step(q, k, v, table_row, pos0):
+        return paged_prefill_attention(
+            q, k, v, table_row, pos0, page_tokens=pt, block_q=bq,
+            interpret=False, vmem_limit_bytes=page["prefill_vmem_bytes"])
+
+    text = _compile_text(step, one_chip,
+                         ((pt, cfg.n_heads, cfg.head_dim), jnp.bfloat16),
+                         (pool, jnp.bfloat16), (pool, jnp.bfloat16),
+                         ((n_pages,), jnp.int32), ((), jnp.int32))
+    kernels = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels and all("%paged_prefill_attention" in ln
+                           for ln in kernels)
 
 
 def test_matmul_cc_compiles_at_planned_tile(one_chip):

@@ -107,6 +107,32 @@ class TestPageFootprint:
         assert page_vmem_bytes(688, 32, 64, 32, 4) > 2 * raw
 
 
+class TestPrefillFootprint:
+    @pytest.mark.parametrize("kv,dtype_bytes,want", [
+        (32, 2, 16), (32, 4, 8), (8, 2, 8), (3, 2, 8), (1, 4, 8)])
+    def test_head_block_is_one_sublane_tile(self, kv, dtype_bytes, want):
+        from repro.core.plan import prefill_kv_block
+        assert prefill_kv_block(kv, dtype_bytes) == want
+
+    def test_grows_with_the_tile(self):
+        from repro.core.plan import prefill_page_vmem_bytes
+        sizes = [prefill_page_vmem_bytes(bq, 1024, 32, 64, 32, 2)
+                 for bq in (8, 256, 512, 1024)]
+        assert sizes == sorted(sizes)
+        assert sizes[-1] > sizes[0]
+
+    @pytest.mark.parametrize("budget", [40 << 20, 96 << 20, 1 << 40])
+    def test_block_q_is_the_tallest_tile_that_fits(self, budget):
+        from repro.core.plan import prefill_block_q, prefill_page_vmem_bytes
+        geometry = (1176, 8, 128, 56, 2)
+        bq = prefill_block_q(1176, *geometry, budget)
+        assert bq % 8 == 0 and 8 <= bq <= 1176
+        if bq < 1176:
+            assert prefill_page_vmem_bytes(bq + 8, *geometry) > budget
+        if bq > 8:
+            assert prefill_page_vmem_bytes(bq, *geometry) <= budget
+
+
 class TestPoolHeadDim:
     @pytest.mark.parametrize("d,want", [(16, 128), (64, 128), (128, 128),
                                         (192, 256)])

@@ -292,6 +292,89 @@ def test_paged_attention_gqa_sublane_pad(kv, group):
     assert out.shape == (s, h, d)       # padded heads sliced back off
 
 
+def _prefill_case(seed, c, h, kv, d, dp, t, n_logical, dtype=jnp.float32):
+    """A chunk's queries ``(C, H, D)`` and a pool whose head dim is stored
+    at ``dp`` lanes (zero beyond ``d``), under a scrambled table row."""
+    rng = np.random.default_rng(seed)
+    p_total = 2 * n_logical + 1
+    q = jnp.asarray(rng.standard_normal((c, h, d)), dtype)
+    k = np.zeros((p_total, t, kv, dp), np.float32)
+    v = np.zeros((p_total, t, kv, dp), np.float32)
+    k[..., :d] = rng.standard_normal((p_total, t, kv, d))
+    v[..., :d] = rng.standard_normal((p_total, t, kv, d))
+    table_row = jnp.asarray(rng.permutation(np.arange(1, p_total))[:n_logical],
+                            jnp.int32)
+    return q, jnp.asarray(k, dtype), jnp.asarray(v, dtype), table_row
+
+
+@pytest.mark.parametrize(
+    "c,h,kv,d,dp,t,pos0,block_q,window,latent", [
+        (40, 8, 8, 64, 128, 16, 0, 16, 0, False),   # MHA, lanes 64 -> 128
+        (24, 12, 3, 32, 32, 8, 8, 8, 0, False),     # GQA, 3 KV heads
+        (20, 4, 2, 16, 16, 8, 13, 8, 0, False),     # pos0 mid-page
+        (21, 4, 2, 16, 16, 8, 16, 8, 0, False),     # C % block_q != 0
+        (16, 4, 2, 16, 16, 8, 24, 8, 10, False),    # window inside the table
+        (24, 8, 1, 40, 128, 8, 5, 16, 0, True),     # MLA latent: K = V
+    ])
+def test_paged_prefill_matches_ref(c, h, kv, d, dp, t, pos0, block_q,
+                                   window, latent):
+    """The chunk-wide prefill kernel equals the decode reference over the
+    table broadcast to every chunk row, each row at its own length."""
+    from repro.kernels.paged_attention import paged_prefill_attention
+    from repro.kernels.ref import paged_attention_ref
+
+    n_logical = -(-(pos0 + c) // t) + 1            # one page past the chunk
+    q, k, v, table_row = _prefill_case(
+        c * 7 + pos0, c, h, kv, d, dp, t, n_logical)
+    if latent:
+        v = k
+    out = paged_prefill_attention(q, k, v, table_row, pos0, window=window,
+                                  page_tokens=t, block_q=block_q)
+    table = jnp.broadcast_to(table_row[None], (c, n_logical))
+    lengths = pos0 + 1 + jnp.arange(c, dtype=jnp.int32)
+    ref = paged_attention_ref(q, k, v, table, lengths, window=window)
+    assert out.shape == (c, h, d)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("pos0,c,block_q,window", [
+    (0, 64, 16, 0), (13, 50, 16, 0), (40, 64, 8, 20), (8, 24, 32, 0)])
+def test_paged_prefill_fetches_live_pages_once(pos0, c, block_q, window):
+    """Walking the prefill grid through the kernel's clamped index map,
+    each tile's distinct block indices are exactly the pages its rows
+    read, and the fetches (changes of block index between consecutive
+    steps) are what the engine counts as ``prefill_kv_page_reads``."""
+    from repro.kernels.paged_attention import (
+        prefill_page_index, prefill_page_reads)
+
+    t, n_pages = 8, 16
+    table = np.arange(100, 100 + n_pages)          # distinct physical pages
+    kw = dict(chunk=c, block_q=block_q, page_tokens=t, window=window,
+              n_pages=n_pages)
+    fetches, prev = 0, None
+    for tile in range(-(-c // block_q)):
+        seen = set()
+        for p in range(n_pages):
+            blk = int(prefill_page_index(tile, p, table, pos0, xp=np, **kw))
+            seen.add(blk)
+            fetches += blk != prev
+            prev = blk
+        rows = range(pos0 + tile * block_q,
+                     pos0 + min((tile + 1) * block_q, c))
+        need = {int(table[j]) for q in rows
+                for j in range(max(0, q - window + 1) // t if window else 0,
+                               q // t + 1)}
+        assert seen == need
+    assert fetches == prefill_page_reads(pos0, c, block_q, t, window,
+                                         n_pages)
+    # The re-read factor: about C / block_q, against C for a kernel that
+    # walks the slot's pages once per row.
+    live = (pos0 + c - 1) // t - (max(0, pos0 - window + 1) // t
+                                  if window else 0) + 1
+    assert fetches <= live * -(-c // block_q)
+
+
 def test_flash_attention_records_clamped_plan():
     """When the sequence forces the kernel below the plan's block, the
     effective plan comes back with the executed blocks and a ``+clamped``

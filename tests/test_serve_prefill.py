@@ -336,3 +336,35 @@ def test_chunk_written_pool_reads_same_under_kernel_and_ref():
     out_r = paged_attention_ref(q, k_pool, v_pool, cache["table"], lengths)
     np.testing.assert_allclose(np.asarray(out_k[0]), np.asarray(out_r[0]),
                                rtol=2e-4, atol=2e-5)
+
+
+def test_chunk_tiles_read_pages_of_earlier_chunks():
+    """A prompt over several pages, cut into page-sized chunks: each later
+    chunk's query tiles read the pages earlier chunks wrote, and the
+    tokens match the cohort path, which attends without pages at all.
+    The engine counts the pages the chunk kernel fetched: each chunk's
+    live pages once per query tile, less a page a tile shares with the
+    one before it."""
+    from repro.kernels.paged_attention import prefill_page_reads
+
+    cfg = get_model_config("llama3.2-1b").reduced()
+    eng = _engine(cfg, "chunked", max_slots=1)
+    t = _chunk_tokens(eng)
+    bq = eng.plan.page_plan()["prefill_block_q"]
+    assert bq < t                         # several tiles in every chunk
+    plen = 4 * t + 5                      # 5 chunks, final one partial
+    rng = np.random.default_rng(11)
+    prompt = _prompt(cfg, plen, rng)
+    outs = eng.generate([prompt], max_new_tokens=3)
+
+    cohort = ServeEngine(
+        cfg, make_host_mesh(),
+        policy=ServePolicy(batching="cohort", max_len=256, max_slots=1),
+        spec=chip_spec(**SMALL))
+    assert outs == cohort.generate([prompt], max_new_tokens=3)
+    pps = eng.metrics["pages_per_slot"]
+    want = sum(prefill_page_reads(a, min(t, plen - a), bq, t, 0, pps)
+               for a in range(0, plen, t))
+    assert eng.metrics["prefill_kv_page_reads"] == want
+    # Later chunks re-read earlier pages: more fetches than chunks.
+    assert want > eng.metrics["prefill_chunks"]
