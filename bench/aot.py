@@ -44,8 +44,8 @@ def main(argv=None) -> int:
     from repro.serve.steps import make_paged_steps
 
     cell = spec.load_cell(args.workload)
-    m = cell.model
-    cfg = spec.model_config(m, cell.config["repro_arch"])
+    fam, m = cell.family, cell.model
+    cfg = fam.model_config(m, cell.config["repro_arch"])
     slots = int(cell.cell["max_slots"])
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -65,7 +65,7 @@ def main(argv=None) -> int:
           f"pool_page_bytes={pool_page_bytes(cfg, eng.page)}")
     cache = jax.eval_shape(lambda: init_paged_cache(
         cfg, eng.model, slots, pages_total, page, pps, dtype))
-    params = jax.eval_shape(lambda: weights.make_weights(m, 0, dtype))
+    params = jax.eval_shape(lambda: weights.make_weights(fam, m, 0, dtype))
     i32 = jnp.int32
     chip_spec()         # the attached-chip lookup is cached: off a TPU, v5e
     with mock.patch.object(jax, "default_backend", lambda: "tpu"):

@@ -39,7 +39,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", default="")
     ap.add_argument("--calls", type=int, default=1)
-    ap.add_argument("--fault", choices=sorted(faults.FAULTS))
+    ap.add_argument("--fault", choices=faults.NAMES)
     ap.add_argument("--fault-seeds", default="")
     args = ap.parse_args(argv)
     import jax
@@ -53,8 +53,8 @@ def main(argv=None) -> int:
         return 2
     enable_compile_cache()
     cell = spec.load_cell(args.workload)
-    m = cell.model
-    cfg = spec.model_config(m, cell.config["repro_arch"])
+    fam, m = cell.family, cell.model
+    cfg = fam.model_config(m, cell.config["repro_arch"])
     c = cell.cell
     slots = int(c["max_slots"])
     device = jax.devices()[0]
@@ -64,7 +64,7 @@ def main(argv=None) -> int:
 
     def new_engine(seed):
         return harness.build_engine(
-            cfg, m, slots, weights.make_weights(m, seed, dtype, device),
+            cfg, m, slots, weights.make_weights(fam, m, seed, dtype, device),
             device)
 
     def readings(engine, seeds, fault=None):
@@ -73,7 +73,7 @@ def main(argv=None) -> int:
             t0 = time.perf_counter()
             engine.params = None
             gc.collect()
-            engine.params = weights.make_weights(m, seed, dtype, device)
+            engine.params = weights.make_weights(fam, m, seed, dtype, device)
             stream = traffic.RequestStream(pairs, m.vocab, seed)
             served = []
             for call in range(args.calls):
@@ -84,8 +84,8 @@ def main(argv=None) -> int:
                                          int(c["sample_requests"]), seed)
             with jax.default_matmul_precision("highest"):
                 prog, ctl = reference.widest_gap(
-                    engine.params, m, [(r.prompt, r.tokens) for r in sample],
-                    control=True)
+                    fam, engine.params, m,
+                    [(r.prompt, r.tokens) for r in sample], control=True)
             row = {"seed": seed, "program": prog, "control": ctl,
                    "requests": len(sample),
                    "served_tokens": sum(len(r.tokens) for r in sample),
@@ -107,7 +107,7 @@ def main(argv=None) -> int:
     if args.fault:
         del engine
         gc.collect()
-        faults.FAULTS[args.fault](setattr)
+        faults.plant(args.fault, fam, setattr)
         engine = new_engine(fault_seeds[0])
         harness.warm_up(engine, pairs, chunk, slots, m.vocab)
         fault_rows = readings(engine, fault_seeds, args.fault)

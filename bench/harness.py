@@ -73,6 +73,7 @@ class Served:
 class RunContext:
     """What the per-layer readers (``bench/metrics``) read."""
 
+    family: Any
     model: Any
     peaks: Dict[str, float]
     dtype_bytes: int
@@ -85,7 +86,7 @@ class RunContext:
 
     def traced_work(self):
         return flops.call_work(
-            self.model, [(len(r.prompt), len(r.tokens))
+            self.family, self.model, [(len(r.prompt), len(r.tokens))
                          for r in self.traced_requests],
             self.chunk, self.dtype_bytes)
 
@@ -171,10 +172,10 @@ class Sessions:
             self.stop()
             self.start()
 
-    def reduce(self, kernels) -> trace_mod.TraceSummary:
+    def reduce(self) -> trace_mod.TraceSummary:
         try:
             return trace_mod.merge([
-                trace_mod.reduce(trace_mod.find_xplane(d), kernels=kernels)
+                trace_mod.reduce(trace_mod.find_xplane(d))
                 for d in self.dirs])
         finally:
             for d in self.dirs:
@@ -307,13 +308,15 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     clock = CompileClock()
     device = jax.devices()[0]
+    fam = cell.family
     m = cell.model.dry() if dry else cell.model
-    cfg = spec.model_config(m, cell.config["repro_arch"])
+    cfg = fam.model_config(m, cell.config["repro_arch"])
     c = cell.cell
     max_slots = int(c["max_slots"])
     if dry:
         max_slots = min(max_slots, DRY_SLOTS)
-    params = weights.make_weights(m, seed, getattr(jnp, m.dtype), device)
+    params = weights.make_weights(fam, m, seed, getattr(jnp, m.dtype),
+                                  device)
     jax.block_until_ready(params)
     engine = build_engine(cfg, m, max_slots, params, device)
     page = engine.page.page_tokens
@@ -374,7 +377,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     if sample:
         with jax.default_matmul_precision("highest"):
             gap, _ = reference.widest_gap(
-                params, m, [(r.prompt, r.tokens) for r in sample])
+                fam, params, m, [(r.prompt, r.tokens) for r in sample])
     else:
         gap = math.inf
     checks["max_logit_gap"] = {"value": gap, "limit": limit}
@@ -402,13 +405,13 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
                           for e in bench["end_to_end"]}
         return result
     t_reduce = t_now()
-    summary = sessions.reduce(KERNELS)
+    summary = sessions.reduce()
     log(f"trace: {len(sessions.dirs)} sessions over {traced_s:.1f}s, "
         f"reduced in {t_now() - t_reduce:.1f}s")
     if summary.dropped:
         raise RuntimeError(f"the profiler dropped {summary.dropped} trace "
                            f"buffers: the device trace is incomplete")
-    ctx = RunContext(model=m, peaks=load_peaks(kind),
+    ctx = RunContext(family=fam, model=m, peaks=load_peaks(kind),
                      dtype_bytes=jnp.dtype(m.dtype).itemsize, chunk=chunk,
                      trace=summary, traced_requests=traced,
                      traced_seconds=traced_s, counters=counters,
@@ -419,14 +422,10 @@ def run(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     result.breakdown = {"device_ops": [[n, s] for n, s in summary.top_ops],
                         "idle_gaps": [[n, s] for n, s in summary.idle_gaps]}
     result.log["programs"] = summary.programs
-    result.log["kernel_s"] = summary.kernel_s
+    result.log["kernel_s"] = {f"{p} {k}": s
+                              for (p, k), s in summary.kernel_s.items()}
     return result
 
-
-#: How the paged attention kernel shows in the trace.  Its name is not in
-#: the operation's name there: the operation is the Mosaic custom call,
-#: and the paged kernel is the only Pallas kernel the served path runs.
-KERNELS = ('custom_call_target="tpu_custom_call"',)
 
 #: Longest profiler session (s); a second of the served path makes about
 #: 10,000 device events.

@@ -2,14 +2,17 @@
 in percent: the least time the chip needs for the decode rows of the
 traced call (the larger of their attention operations over peak FLOP/s
 and the K/V of each row's real context, read once, over HBM bandwidth;
-``bench/flops.py``) over the kernel's device seconds inside
-``jit_decode_fn`` runs."""
+the family's ``decode_row``) over the device seconds of the Mosaic kernel
+``paged_attention`` inside ``jit_decode_fn`` runs.  Another kernel of
+the same program is not counted; a program that runs kernels but not
+this one raises."""
 
 PROGRAM = "jit_decode_fn"
+KERNEL = "paged_attention"
 
 
 def read(ctx):
-    kernel_s = ctx.trace.kernel_s.get(PROGRAM, 0.0)
+    kernel_s = ctx.trace.kernel_seconds(PROGRAM, KERNEL)
     _, work = ctx.traced_work()
     if kernel_s <= 0 or not work.rows:
         return None

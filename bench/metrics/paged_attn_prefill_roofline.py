@@ -2,14 +2,17 @@
 chunks, in percent: the least time the chip needs for the traced call's
 chunks (attention operations of every row over peak FLOP/s, or each
 chunk's K/V context read once over HBM bandwidth, whichever is larger;
-``bench/flops.py``) over the kernel's device seconds inside
-``jit_prefill_chunk_fn`` runs."""
+the family's ``prefill_chunk``) over the device seconds of the Mosaic
+kernel ``paged_prefill_attention`` inside ``jit_prefill_chunk_fn`` runs.
+Another kernel of the same program is not counted; a program that runs
+kernels but not this one raises."""
 
 PROGRAM = "jit_prefill_chunk_fn"
+KERNEL = "paged_prefill_attention"
 
 
 def read(ctx):
-    kernel_s = ctx.trace.kernel_s.get(PROGRAM, 0.0)
+    kernel_s = ctx.trace.kernel_seconds(PROGRAM, KERNEL)
     work, _ = ctx.traced_work()
     if kernel_s <= 0 or not work.rows:
         return None

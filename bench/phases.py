@@ -247,10 +247,10 @@ def _reading(harness, found: Dict[str, Any]):
     around the traced call (the one given ``between_steps``)."""
     reduce0, call0 = harness.Sessions.reduce, harness._call
 
-    def sessions_reduce(self, kernels):
+    def sessions_reduce(self):
         found["phases"] = merge([reduce(trace_mod.find_xplane(d))
                                  for d in self.dirs])
-        return reduce0(self, kernels)
+        return reduce0(self)
 
     def call(engine, batch, call, t_now, between_steps=None):
         mark = ring_mark(engine) if between_steps is not None else None

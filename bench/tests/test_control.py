@@ -23,10 +23,11 @@ def test_control_fails_where_the_run_passes(name):
                       dry=True, log=lambda _m: None)
     assert res.correct, res.checks
     m = cell.model.dry()
-    w = weights.make_weights(m, SEED, jax.numpy.bfloat16)
+    w = weights.make_weights(cell.family, m, SEED, jax.numpy.bfloat16)
     with jax.default_matmul_precision("highest"):
         prog, ctl = reference.widest_gap(
-            w, m, [(r.prompt, r.tokens) for r in res.sample], control=True)
+            cell.family, w, m, [(r.prompt, r.tokens) for r in res.sample],
+            control=True)
     limit = float(cell.cell["limit_max_logit_gap"])
     assert prog == res.checks["max_logit_gap"]["value"] <= limit
     assert ctl > limit, (prog, ctl, limit)

@@ -17,24 +17,24 @@ from bench import faults, harness, spec
 CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 
 
-def _run(name, seed=321):
-    cell = spec.load_cell(name)
+def _run(cell, seed=321):
     return harness.run(cell, seed, 2.0, False, t_process=time.perf_counter(),
                        dry=True, log=lambda _m: None)
 
 
 @pytest.mark.parametrize("name", CELLS)
 def test_sound_run_is_correct(name):
-    res = _run(name)
+    res = _run(spec.load_cell(name))
     assert res.correct, res.checks
     assert res.log["compiles_in_window"] == 0
 
 
 @pytest.mark.parametrize("name", CELLS)
-@pytest.mark.parametrize("fault", sorted(faults.FAULTS))
+@pytest.mark.parametrize("fault", faults.NAMES)
 def test_fault_is_not_correct(monkeypatch, fault, name):
-    faults.FAULTS[fault](monkeypatch.setattr)
-    res = _run(name)
+    cell = spec.load_cell(name)
+    faults.plant(fault, cell.family, monkeypatch.setattr)
+    res = _run(cell)
     assert not res.correct, res.checks
     gap = res.checks["max_logit_gap"]
     assert gap["value"] > gap["limit"]

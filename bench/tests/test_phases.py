@@ -21,8 +21,8 @@ PINNED = {
     "busy_s": 0.00016404400000000002,
     "extent_s": 0.0017653100000000002,
     "programs": {"jit_decode_fn": (3, 0.000164072)},
-    "kernel_s": {"jit_decode_fn": 0.00016185300000000002},
-    "kernel_calls": {"jit_decode_fn": 3},
+    "kernel_s": {("jit_decode_fn", "decode_fn"): 0.00016185300000000002},
+    "kernel_calls": {("jit_decode_fn", "decode_fn"): 3},
     "top_ops": [("jit_decode_fn %decode_fn.1 [custom call]",
                  0.00016185300000000002),
                 ("jit_decode_fn %copy", 1.3220000000000002e-06),
@@ -36,7 +36,7 @@ PINNED = {
 
 @pytest.mark.parametrize("key", sorted(PINNED))
 def test_trace_without_spans_reduces_as_before(key):
-    summary = trace.reduce(str(TRACE), kernels=harness.KERNELS)
+    summary = trace.reduce(str(TRACE))
     assert getattr(summary, key) == PINNED[key]
 
 
@@ -178,7 +178,7 @@ def test_reading_sees_the_traced_call_and_each_session():
         served = harness._call(eng, batch, 1, time.perf_counter,
                                sessions.tick)
         sessions.stop()
-        sessions.reduce(harness.KERNELS)
+        sessions.reduce()
     assert (harness._call, harness.Sessions.reduce) == (call0, reduce0)
     assert found["waits"] == [0.0, pytest.approx(0.5), pytest.approx(1.0)]
     assert found["ttft_s"] == pytest.approx(

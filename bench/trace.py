@@ -8,8 +8,11 @@ A device plane (``/device:TPU:<n>``) has a line of program executions
 - ``busy_s``: the union of the operation intervals, averaged over the
   devices that ran any;
 - ``programs``: for each program name, its runs and their device seconds;
-- ``kernel_s``: for each program, the device seconds of the operations
-  whose name holds one of the kernel names asked for, inside its runs;
+- ``kernel_s``, ``kernel_calls``: the device seconds and the runs of
+  each Mosaic kernel (a ``tpu_custom_call`` operation), keyed by
+  ``(program, kernel)``: the program whose run holds it, and the
+  kernel's name (``kernel_name``), so that each kernel of a program is
+  read apart;
 - ``top_ops``: leaf operations (not a loop holding others) by total
   device seconds, named ``<program> <instruction>``;
 - ``idle_gaps``: device idle time between operations, each of the
@@ -21,7 +24,9 @@ A device plane (``/device:TPU:<n>``) has a line of program executions
 
 An operation's name in the trace is its HLO instruction
 (``%name = type opcode(operands), ...``); ``<instruction>`` is the part
-before `` = ``, with ``[custom call]`` added for a Mosaic kernel.
+before `` = ``, with ``[custom call]`` added for a Mosaic kernel.  A
+Mosaic kernel's instruction is named after its ``pallas_call``'s
+``name``: ``%paged_attention.9`` is the kernel ``paged_attention``.
 
 All times are seconds.  Event times in one file share one clock.
 """
@@ -40,6 +45,9 @@ import numpy as np
 MODULE_LINE = "XLA Modules"
 OP_LINE = "XLA Ops"
 _SUFFIX = re.compile(r"\(\d+\)$")
+_INSTR_SUFFIX = re.compile(r"(\.\d+)+$")
+#: What marks a Mosaic (Pallas) kernel among the operations.
+MOSAIC = 'custom_call_target="tpu_custom_call"'
 
 
 @dataclass
@@ -48,11 +56,23 @@ class TraceSummary:
     extent_s: float = 0.0
     n_devices: int = 0
     programs: Dict[str, Tuple[int, float]] = field(default_factory=dict)
-    kernel_s: Dict[str, float] = field(default_factory=dict)
-    kernel_calls: Dict[str, int] = field(default_factory=dict)
+    kernel_s: Dict[Tuple[str, str], float] = field(default_factory=dict)
+    kernel_calls: Dict[Tuple[str, str], int] = field(default_factory=dict)
     top_ops: List[Tuple[str, float]] = field(default_factory=list)
     idle_gaps: List[Tuple[str, float]] = field(default_factory=list)
     dropped: int = 0
+
+    def kernel_seconds(self, program: str, kernel: str) -> float:
+        """The device seconds of ``kernel`` inside ``program``'s runs; 0
+        where the program ran no Mosaic kernel (the CPU's interpret
+        mode).  A program that ran Mosaic kernels but none of that name
+        raises: a renamed ``pallas_call`` would otherwise leave its
+        roofline silent."""
+        found = sorted(k for p, k in self.kernel_s if p == program)
+        if found and kernel not in found:
+            raise ValueError(f"no Mosaic kernel {kernel!r} in {program}; "
+                             f"it ran {found}")
+        return self.kernel_s.get((program, kernel), 0.0)
 
 
 def find_xplane(log_dir: str) -> str:
@@ -136,10 +156,15 @@ def op_label(program: str, hlo: str) -> str:
     return f"{program} {name}"
 
 
-def reduce(path: str, kernels: Sequence[str] = (),
-           top: int = 10) -> TraceSummary:
-    """Reduce the trace at ``path``; ``kernels`` are substrings of the
-    operation names counted into ``kernel_s``."""
+def kernel_name(hlo: str) -> str:
+    """The kernel of a Mosaic operation's HLO text: its instruction name
+    without ``%`` and the ``.<n>`` the compiler numbers it with
+    (``%paged_attention.9 = ...`` -> ``paged_attention``)."""
+    return _INSTR_SUFFIX.sub("", hlo.split(" = ", 1)[0].lstrip("%"))
+
+
+def reduce(path: str, top: int = 10) -> TraceSummary:
+    """Reduce the trace at ``path``."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -147,8 +172,8 @@ def reduce(path: str, kernels: Sequence[str] = (),
     host = _host_events(planes)
     out = TraceSummary()
     progs: Dict[str, List[float]] = defaultdict(lambda: [0, 0.0])
-    kern: Dict[str, float] = defaultdict(float)
-    kcalls: Dict[str, int] = defaultdict(int)
+    kern: Dict[Tuple[str, str], float] = defaultdict(float)
+    kcalls: Dict[Tuple[str, str], int] = defaultdict(int)
     ops: Dict[str, float] = defaultdict(float)
     gaps_by: Dict[str, float] = defaultdict(float)
     busy, lo, hi = [], np.inf, -np.inf
@@ -182,9 +207,10 @@ def reduce(path: str, kernels: Sequence[str] = (),
         for n, d, prog, h in zip(on, od, prog_of, holds.tolist()):
             if not h:
                 ops[op_label(prog, n)] += d * 1e-9
-            if any(k in n for k in kernels):
-                kern[prog] += d * 1e-9
-                kcalls[prog] += 1
+            if MOSAIC in n:
+                key = (prog, kernel_name(n))
+                kern[key] += d * 1e-9
+                kcalls[key] += 1
         iv = _union(os_, os_ + od)
         busy.append(sum(b - a for a, b in iv) * 1e-9)
         lo, hi = min(lo, iv[0][0]), max(hi, iv[-1][1])
@@ -255,5 +281,5 @@ if __name__ == "__main__":
     import sys
 
     print(describe(sys.argv[1]))
-    for k, v in vars(reduce(sys.argv[1], kernels=sys.argv[2:])).items():
+    for k, v in vars(reduce(sys.argv[1])).items():
         print(k, v)
